@@ -24,18 +24,20 @@ def main():
 
     if args.full_100m:
         # ~100M params: 8 layers × d512 × ff2048, 32k vocab
-        argv = ["--arch", "yi-9b", "--steps", str(args.steps or 300),
+        argv = ["--arch", "yi-9b", "--reduced",
+                "--steps", str(args.steps or 300),
                 "--batch", "16", "--seq", "256",
                 "--ckpt-dir", "/tmp/repro_ckpt_100m"]
-        # widen the reduced config via env-free override below
-        import repro.configs as C
+        # widen the reduced config: the launcher calls the reduced()
+        # it imported, so that is the name to override
         base = reduced(get_config("yi-9b"))
         big = dataclasses.replace(base, n_layers=8, d_model=512, d_head=64,
                                   n_heads=8, n_kv_heads=4, d_ff=2048,
                                   vocab_size=32768)
-        C.reduced = lambda _cfg, _big=big: _big  # driver uses reduced()
+        train_mod.reduced = lambda _cfg, _big=big: _big
     else:
-        argv = ["--arch", "yi-9b", "--steps", str(args.steps or 120),
+        argv = ["--arch", "yi-9b", "--reduced",
+                "--steps", str(args.steps or 120),
                 "--batch", "8", "--seq", "64",
                 "--ckpt-dir", "/tmp/repro_ckpt_quick"]
     if args.resume:

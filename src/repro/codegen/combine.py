@@ -69,6 +69,12 @@ class Combine:
         the output block the reduction produces."""
         raise NotImplementedError
 
+    def state_shapes(self, out_width: int) -> tuple[tuple[int, ...], ...]:
+        """Shape of each f32 accumulator the emitter allocates: one lane
+        row per component unless a combinator lays its state out in
+        2-D (``OnlineSoftmax``)."""
+        return tuple((1, w) for w in self.state_widths(out_width))
+
     def init(self, shapes: Sequence[tuple[int, ...]]) -> tuple:
         """Identity state: one f32 array per component shape."""
         raise NotImplementedError
@@ -118,21 +124,28 @@ class MaxCombine(Combine):
 class OnlineSoftmax(Combine):
     """Numerically-stable streaming softmax-weighted average.
 
-    State is ``(m, num, den)`` per softmax group: running score max,
-    max-rescaled weighted value sum (``groups * vwidth`` lanes wide) and
-    max-rescaled weight sum.  ``finalize`` divides, so a spec reduced
-    with this combinator writes ``softmax(scores) @ V`` in ONE sweep of
-    the streamed operands — the single-pass flash-decode pattern.
+    State is ``(m, num, den)`` per softmax group, kept in 2-D
+    ``[groups, …]`` form: running score max ``m`` and max-rescaled
+    weight sum ``den`` as ``[groups, 1]`` columns, max-rescaled weighted
+    value sum ``num`` as ``[groups, vwidth]`` rows (the emitter's
+    accumulators add a leading 1).  Merge and finalize are then plain
+    broadcasts: no reshape moves data between the lane and sublane
+    axes, which Mosaic does not lower.  ``finalize`` divides, so a spec
+    reduced with this combinator writes ``softmax(scores) @ V`` in ONE
+    sweep of the streamed operands — the single-pass flash-decode
+    pattern.
 
-    The body must return the block's partial state ``(m, num, den)``:
-      * ``m``   — per-group max of the block's scores,
-      * ``num`` — sum of ``exp(score - m) * value`` over the block,
-      * ``den`` — sum of ``exp(score - m)`` over the block.
+    The body must return the block's partial state ``(m, num, den)`` in
+    that layout (any leading batch dims are kept):
+      * ``m``   — ``[groups, 1]`` per-group max of the block's scores,
+      * ``num`` — ``[groups, vwidth]`` sum of ``exp(score - m) * value``,
+      * ``den`` — ``[groups, 1]`` sum of ``exp(score - m)``.
 
     ``with_lse=True`` makes ``finalize`` ALSO emit the per-group
-    log-sum-exp ``m + log(den)`` as a second output block — the
-    flash-attention side statistic sharded-attention combines need; the
-    spec then declares a second (``groups``-wide) write access.
+    log-sum-exp ``m + log(den)`` (``[groups, 1]``) as a second output
+    block — the flash-attention side statistic sharded-attention
+    combines need; the spec then declares a second (``groups``-wide)
+    write access.
     """
 
     groups: int            # independent softmax rows in the output
@@ -150,16 +163,16 @@ class OnlineSoftmax(Combine):
                 f"({self.groups}) * vwidth ({self.vwidth})")
         return (self.groups, out_width, self.groups)
 
+    def state_shapes(self, out_width):
+        self.state_widths(out_width)            # validates the width
+        g = self.groups
+        return ((1, g, 1), (1, g, self.vwidth), (1, g, 1))
+
     def init(self, shapes):
         m_shape, num_shape, den_shape = shapes
         return (jnp.full(m_shape, NEG_INF, jnp.float32),
                 jnp.zeros(num_shape, jnp.float32),
                 jnp.zeros(den_shape, jnp.float32))
-
-    def _rescale(self, num, alpha):
-        shape = num.shape
-        num = num.reshape(shape[:-1] + (self.groups, self.vwidth))
-        return (num * alpha[..., None]).reshape(shape)
 
     def merge(self, state, part):
         m1, n1, d1 = state
@@ -167,19 +180,13 @@ class OnlineSoftmax(Combine):
         m = jnp.maximum(m1, m2)
         a1 = jnp.exp(m1 - m)
         a2 = jnp.exp(m2 - m)
-        return (m,
-                self._rescale(n1, a1) + self._rescale(n2, a2),
-                d1 * a1 + d2 * a2)
+        return (m, n1 * a1 + n2 * a2, d1 * a1 + d2 * a2)
 
     def finalize(self, state):
         m, num, den = state
-        shape = num.shape
-        num = num.reshape(shape[:-1] + (self.groups, self.vwidth))
         den = jnp.maximum(den, self.eps)
-        out = (num / den[..., None]).reshape(shape)
-        if not self.with_lse:
-            return out
-        return out, m + jnp.log(den)
+        out = num / den
+        return (out, m + jnp.log(den)) if self.with_lse else out
 
 
 SUM = SumCombine()

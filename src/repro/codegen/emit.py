@@ -34,8 +34,8 @@ Specs with multiple ``writes`` lower to multiple Pallas output refs —
 one store stream (or manual staging ring) per output, no stacked free
 axis and no unstack copies; the body returns one block per write.  Each
 write carries its OWN access map (``_plan_writes``): a rank-1 row
-statistic lowers to a ``(d, bm)`` block next to a matrix write's
-``(d, bm, bn)``, a free-axis side output to its own whole-extent tile,
+statistic lowers to a ``(d, bm, 1)`` column block next to a matrix
+write's ``(d, bm, bn)``, a free-axis side output to its own whole-extent tile,
 and stream reductions finalize one block per write through a
 *finalizing* combinator (``OnlineSoftmax(with_lse=True)`` emits the
 attention row and its log-sum-exp from one accumulated state).
@@ -111,6 +111,7 @@ class _Operand:
     kind: str              # "stream2d" | "stream1d" | "resident"
     taps: int = 1          # row-tap operands per stream
     squeeze: bool = False  # drop the artificial leading dim of a 1-D read
+    env_shape: Optional[tuple] = None   # body block shape of a lifted read
 
     def load(self, refs: Sequence, base: int, k: int, lanes=None):
         """Build this access's env block for stream ``k`` (optionally a
@@ -119,17 +120,33 @@ class _Operand:
             blk = refs[base][...]
             if self.squeeze:
                 blk = blk[0]
+            elif self.env_shape is not None:
+                blk = blk.reshape(self.env_shape)
             return blk if lanes is None else blk[lanes]
         if self.kind == "stream1d":
             blk = refs[base + k][...]
             # drop the artificial leading dim of an unbatched 1-D read;
-            # batched row streams keep their (1,)*nb batch-block dims
-            return blk[0] if self.squeeze else blk
+            # batched row streams get back their (1,)*nb batch dims
+            if self.squeeze:
+                return blk[0]
+            return blk.reshape(self.env_shape)
         if self.taps == 1:
             blk = refs[base + k][...]
             return blk if lanes is None else blk[:, lanes]
         rows = [refs[base + k * self.taps + t][...] for t in range(self.taps)]
         return jnp.concatenate(rows, axis=0)   # halo-widened block
+
+
+def _lift(x, nb: int):
+    """Mosaic tiles the last two dims of every block at (8, 128) or their
+    full extent, so a batch dim may not be one of them.  Give a batched
+    operand with fewer than two non-batch dims unit dims after its batch
+    prefix (``[b, F]`` → ``[b, 1, F]``); returns the array and the count
+    of unit dims added.  Its batch block dims are then squeezed."""
+    k = max(0, 2 - (x.ndim - nb)) if nb else 0
+    if k:
+        x = x.reshape(x.shape[:nb] + (1,) * k + x.shape[nb:])
+    return x, k
 
 
 def _lower_reads(sched: transforms.Schedule, bp: transforms.BlockPlan,
@@ -165,10 +182,12 @@ def _lower_reads(sched: transforms.Schedule, bp: transforms.BlockPlan,
             if nb == 0 and x.ndim == 1:
                 x, squeeze = x.reshape(1, -1), True
                 dim_vars = (None,) + dim_vars
+            x, lifted = _lift(x, nb)
+            dim_vars = dim_vars[:nb] + (None,) * lifted + dim_vars[nb:]
             block, codes = [], []
             for dv, size in zip(dim_vars, x.shape):
                 if dv in info.batch_axes:
-                    block.append(1)
+                    block.append(pl.squeezed if lifted else 1)
                     codes.append(pos[dv])
                 elif (dv == info.vector_axis and not full
                         and acc.halo_of(dv) == (0, 0)):
@@ -177,11 +196,14 @@ def _lower_reads(sched: transforms.Schedule, bp: transforms.BlockPlan,
                 else:
                     block.append(size)
                     codes.append(-1)
+            env_shape = ((1,) * nb + tuple(block[nb + lifted:])
+                         if lifted else None)
 
             def imap(*g, _codes=tuple(codes)):
                 return tuple(0 if c < 0 else g[c] for c in _codes)
             ops.append(_Operand(acc, [x], [pl.BlockSpec(tuple(block), imap)],
-                                "resident", squeeze=squeeze))
+                                "resident", squeeze=squeeze,
+                                env_shape=env_shape))
         elif (len(rest) == 2 and rest[0] == info.stride_axis
                 and (rest[1] == info.vector_axis
                      or rest[1] in info.free_axes)):
@@ -222,19 +244,21 @@ def _lower_reads(sched: transforms.Schedule, bp: transforms.BlockPlan,
                     f"{spec.name}: halo on rank-1 streamed {acc.array!r}")
             # [batch…, stride]: D rank-1 row streams (one batch element
             # per grid step), e.g. decode_attn's kv_len validity mask.
-            # Unbatched operands get an artificial leading dim (squeezed
-            # back at load).
-            x2 = x if nb else x.reshape(1, -1)
+            # Rows ride the lane axis: unbatched operands get a leading
+            # unit dim (squeezed back at load), batched ones a unit dim
+            # after their squeezed batch prefix (see ``_lift``).
+            x2 = _lift(x, nb)[0] if nb else x.reshape(1, -1)
+            lead_block = (pl.squeezed,) * nb + (1,)
             specs, operands = [], []
             for k in range(d):
                 def imap(*g, _k=k, _bpos=bpos):
-                    lead = (tuple(g[p] for p in _bpos) if _bpos else (0,))
+                    lead = tuple(g[p] for p in _bpos) + (0,)
                     return lead + (g[row_pos] + _k * segb,)
-                specs.append(pl.BlockSpec((1,) * max(nb, 1) + (bp.bm,),
-                                          imap))
+                specs.append(pl.BlockSpec(lead_block + (bp.bm,), imap))
                 operands.append(x2)
             ops.append(_Operand(acc, operands, specs, "stream1d",
-                                squeeze=not nb))
+                                squeeze=not nb,
+                                env_shape=(1,) * nb + (bp.bm,)))
         else:
             raise NotImplementedError(
                 f"{spec.name}: access {acc.array!r}{acc.index} not "
@@ -363,6 +387,11 @@ def _plan_writes(spec: loopir.TraversalSpec, bp: transforms.BlockPlan,
                 shape_tail.append(spec.axis(v).extent)
                 block_tail.append(spec.axis(v).extent)
                 imap_tail.append(None)
+        if not tail:
+            # a rank-1 row statistic is a [rows, 1] column: its rows
+            # take the sublane axis, so the block needs bm % 8 == 0
+            # instead of bm % 128 (lane-major rows)
+            block_tail, shape_tail, imap_tail = [1], [1], [None]
         plans.append(_WritePlan(
             access=acc, nb=len(bvars),
             bpos=tuple(pos[v] for v in bvars),
@@ -483,7 +512,8 @@ def _emit_streaming(sched, bp, arrays, scalars, interpret: bool):
     res = tuple(
         o.reshape(*wp.batch_ext, *wp.shape_tail, d * seg_rows)
         if wp.transposed
-        else o.reshape(*wp.batch_ext, d * seg_rows, *wp.shape_tail)
+        else o.reshape(*wp.batch_ext, d * seg_rows,
+                       *(wp.shape_tail if wp.tail else ()))   # [rows, 1]
         for o, wp in zip(out, wplans))
     return res[0] if n_out == 1 else res
 
@@ -536,24 +566,26 @@ def _emit_reduction(sched, bp, arrays, scalars, interpret: bool):
         for k in range(d):
             blocks = _as_blocks(spec.body(env_full(refs, k)), spec)
             for acc, res, comb in zip(accs, blocks, combs):
-                part = _fit(res, (bp.bm,)).astype(jnp.float32)
-                (v,) = comb.merge((acc[k, :],), (part,))
-                acc[k, :] = v
+                part = _fit(res, (bp.bm, 1)).astype(jnp.float32)
+                (v,) = comb.merge((acc[k],), (part,))
+                acc[k] = v
 
         @pl.when(j == pl.num_programs(col_pos) - 1)
         def _():
             for o_ref, acc in zip(o_refs, accs):
                 o_ref[...] = acc[...].astype(o_ref.dtype)
 
+    # per-row results are [rows, 1] columns (rows on sublanes)
     out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=[pl.BlockSpec((d, bp.bm), lambda *g: (0, g[row_pos]))
+        out_specs=[pl.BlockSpec((d, bp.bm, 1),
+                                lambda *g: (0, g[row_pos], 0))
                    for _ in range(n_out)],
-        out_shape=[jax.ShapeDtypeStruct((d, seg_rows), jnp.dtype(dt))
+        out_shape=[jax.ShapeDtypeStruct((d, seg_rows, 1), jnp.dtype(dt))
                    for dt in out_dtypes],
-        scratch_shapes=[pltpu.VMEM((d, bp.bm), jnp.float32)
+        scratch_shapes=[pltpu.VMEM((d, bp.bm, 1), jnp.float32)
                         for _ in range(n_out)],
         interpret=interpret,
     )(*operands)
@@ -597,51 +629,64 @@ def _emit_stream_reduction(sched, bp, arrays, scalars, interpret: bool):
             f"finalizing combinator producing one block per write; "
             f"{comb.name!r} finalizes the accumulated state identically")
 
-    out_specs, out_shapes, finals, widths_per = [], [], [], []
+    plans, widths_per = [], []
     for acc_w in spec.writes:
         bvars = tuple(v for v in acc_w.index if v in info.batch_axes)
         rest = _write_rest(acc_w, info)
-        nb = len(bvars)
-        bpos = tuple(pos[v] for v in bvars)
         batch_ext = tuple(spec.axis(v).extent for v in bvars)
         if rest == (info.vector_axis,):
             w = bp.bn                      # per-col-block partial outputs
-
-            def out_imap(*g, _bpos=bpos):
-                return tuple(g[p] for p in _bpos) + (0, g[col_pos])
-            block = (1,) * nb + (1, w)
-            out_shapes.append(batch_ext + (1, bp.cols))
-            finals.append(batch_ext + (bp.cols,))
             if comb.n_state > 1 and bp.bn != bp.cols:
                 raise NotImplementedError(
                     f"{spec.name}: a paired-state combinator cannot split "
                     "the vector axis across grid steps (state widths are "
                     "derived from the whole output row); set "
                     "full_width=True")
-        elif len(rest) == 1 and rest[0] in info.free_axes:
+        elif rest and all(v in info.free_axes for v in rest):
             if bp.bn != bp.cols:
                 raise NotImplementedError(
                     f"{spec.name}: free-axis reduction output "
                     f"{acc_w.array!r} needs full_width=True (vector axis "
                     "consumed in the body)")
-            w = spec.axis(rest[0]).extent
-
-            def out_imap(*g, _bpos=bpos):
-                return tuple(g[p] for p in _bpos) + (0,)
-            block = (1,) * nb + (w,)
-            out_shapes.append(batch_ext + (w,))
-            finals.append(batch_ext + (w,))
+            w = 1
+            for v in rest:
+                w *= spec.axis(v).extent
         else:
             raise NotImplementedError(
                 f"{spec.name}: stride-reduction write {acc_w.array!r}"
-                f"{acc_w.index} must be the vector axis or one free axis "
+                f"{acc_w.index} must be the vector axis or free axes "
                 "(plus batch)")
-        out_specs.append(pl.BlockSpec(block, out_imap))
+        plans.append((rest, tuple(pos[v] for v in bvars), batch_ext))
         widths_per.append(w)
     # accumulator geometry follows the PRIMARY (first) write: its width
     # is what the body's partial state covers; side writes are derived
     # by finalize from the same state
-    widths = comb.state_widths(widths_per[0])
+    state_shapes = comb.state_shapes(widths_per[0])
+    res_shapes = [r.shape for r in _as_blocks(jax.eval_shape(
+        comb.finalize, tuple(jax.ShapeDtypeStruct(s, jnp.float32)
+                             for s in state_shapes)), spec)]
+
+    out_specs, out_shapes, finals = [], [], []
+    for (rest, bpos, batch_ext), w, res_shape in zip(plans, widths_per,
+                                                     res_shapes):
+        nb = len(bpos)
+        if rest == (info.vector_axis,):
+            def out_imap(*g, _bpos=bpos):
+                return tuple(g[p] for p in _bpos) + (0, g[col_pos])
+            block = (1,) * nb + (1, w)
+            out_shapes.append(batch_ext + (1, bp.cols))
+            finals.append(batch_ext + (bp.cols,))
+        else:
+            # free-axis outputs take the finalized block's own layout
+            # (a lane row, or [groups, …] for the online softmax), so
+            # the batch dims stay out of the tiled last two
+            def out_imap(*g, _bpos=bpos, _n=len(res_shape)):
+                return tuple(g[p] for p in _bpos) + (0,) * _n
+            block = (1,) * nb + res_shape
+            out_shapes.append(batch_ext + res_shape)
+            finals.append(batch_ext + tuple(spec.axis(v).extent
+                                            for v in rest))
+        out_specs.append(pl.BlockSpec(block, out_imap))
 
     def kernel(*refs):
         o_refs = refs[len(operands):len(operands) + n_out]
@@ -680,7 +725,7 @@ def _emit_stream_reduction(sched, bp, arrays, scalars, interpret: bool):
         out_specs=out_specs,
         out_shape=[jax.ShapeDtypeStruct(shape, jnp.dtype(dt))
                    for shape, dt in zip(out_shapes, out_dtypes)],
-        scratch_shapes=[pltpu.VMEM((1, wi), jnp.float32) for wi in widths],
+        scratch_shapes=[pltpu.VMEM(s, jnp.float32) for s in state_shapes],
         interpret=interpret,
     )(*operands)
     res = tuple(o.reshape(f) for o, f in zip(out, finals))

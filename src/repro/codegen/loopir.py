@@ -33,6 +33,7 @@ pure-jnp ref interpreter :func:`evaluate`.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Callable, Mapping, Optional, Sequence
 
 import jax
@@ -491,6 +492,7 @@ def evaluate(spec: TraversalSpec, inputs: Sequence[Any]):
     env: dict[str, Any] = {a.array: x for a, x in zip(spec.reads, arrays)}
     env.update(zip(spec.scalars, scalars))
     out = spec.body(env)
+    finalized = False
     # a per-write reduce tuple is single-state / non-finalizing by
     # construction (__post_init__): the body already reduced the full
     # extent, so there is no state to finalize here
@@ -505,6 +507,7 @@ def evaluate(spec: TraversalSpec, inputs: Sequence[Any]):
                     f"(n_state={comb.n_state})")
             out = comb.finalize(tuple(jnp.asarray(o, jnp.float32)
                                       for o in state))
+            finalized = True
     outs = out if isinstance(out, tuple) else (out,)
     if len(outs) != len(spec.writes):
         raise ValueError(f"{spec.name}: body returned {len(outs)} blocks "
@@ -513,7 +516,12 @@ def evaluate(spec: TraversalSpec, inputs: Sequence[Any]):
     for o, shape, dt in zip(outs, spec.out_shapes(),
                             spec.out_dtypes(arrays)):
         o = jnp.asarray(o)
-        if o.shape != shape and not spec.reads:
+        if finalized and o.shape != shape and o.size == math.prod(shape):
+            # finalize keeps the combinator's state layout (the online
+            # softmax's [groups, 1] columns); each write takes its own
+            # shape, as the emitter's output blocks do
+            o = o.reshape(shape)
+        elif o.shape != shape and not spec.reads:
             o = jnp.broadcast_to(o, shape)   # writes-only / fill bodies
         res.append(o.astype(dt))
     return res[0] if len(res) == 1 else tuple(res)

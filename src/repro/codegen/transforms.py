@@ -192,6 +192,38 @@ class BlockPlan:
     cols: int          # padded vector-axis extent (bn | cols)
 
 
+def row_align(spec, info: Optional[loopir.NestInfo] = None) -> int:
+    """Row multiple a block needs on the chip.  Mosaic tiles a block's
+    last two dims at (8, 128) unless a dim spans the whole array: row
+    blocks put rows on sublanes (8), but a rank-1 ``[batch…, stride]``
+    row stream (decode attention's validity mask) and a transposed
+    ``(vector, stride)`` store put them on lanes (128).  A tuple of
+    specs (a composite sharing one config) needs the largest."""
+    if isinstance(spec, tuple):
+        return max(row_align(s) for s in spec)
+    info = info or loopir.classify(spec)
+
+    def rest(acc):
+        return tuple(v for v in acc.index if v not in info.batch_axes)
+    if (any(rest(a) == (info.stride_axis,) for a in spec.reads)
+            or any(rest(w) == (info.vector_axis, info.stride_axis)
+                   for w in spec.writes)):
+        return LANE
+    return 8
+
+
+def _row_block(seg: int, prefer: int, align: int) -> int:
+    """Block rows per stream: the largest multiple of ``align`` dividing
+    ``seg`` that is <= max(prefer, align); failing that, the largest
+    divisor <= prefer.  The interpreter takes any block; on the chip
+    ``kernels.common.resolve_config`` clamps D so that each stream holds
+    whole tiles where the row count allows, and a lone stream is padded
+    to one (``plan_blocks``) unless the stride axis is reduced."""
+    if seg % align == 0:
+        return align * choose_block(seg // align, max(prefer // align, 1))
+    return choose_block(seg, prefer)
+
+
 def plan_blocks(spec: loopir.TraversalSpec,
                 config: StridingConfig,
                 prefer_bm: int = 8) -> BlockPlan:
@@ -216,9 +248,18 @@ def plan_blocks(spec: loopir.TraversalSpec,
     rows_p = pad_to_multiple(rows, d)
     row_halo = info.row_halo != (0, 0)
     col_halo = info.col_halo != (0, 0)
-    if config.block_rows:
-        prefer_bm = config.block_rows
-    bm = 1 if row_halo else choose_block(rows_p // d, prefer_bm)
+    align = row_align(spec, info)
+    if (d == 1 and rows_p % align and not row_halo
+            and not info.stride_reduction):
+        # one stream that is not a whole number of tiles: pad it to one
+        # (pad + crop; a reduced stride axis must not pad, see emit_spec)
+        rows_p = pad_to_multiple(rows, align)
+    if row_halo:
+        bm = 1
+    elif config.block_rows:
+        bm = choose_block(rows_p // d, config.block_rows)
+    else:
+        bm = _row_block(rows_p // d, prefer_bm, align)
     if col_halo or spec.full_width:
         bn, cols_p = cols, cols           # full-width blocks, no col grid
     else:

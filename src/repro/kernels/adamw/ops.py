@@ -79,6 +79,9 @@ def adamw_update(p: jax.Array, g: jax.Array, m: jax.Array, v: jax.Array,
     # 4 read + 3 write arrays per stride: write-stream cap applies
     traffic = Traffic(rows=rows, cols=cols, dtype=p.dtype,
                       read_arrays=4, write_arrays=3)
+    p2, g2, m2, v2 = (jax.ShapeDtypeStruct((rows, cols), a.dtype)
+                      for a in (p, g, m, v))
     cfg = common.resolve_config("adamw_update", p.shape, p.dtype, config,
-                                rows, _DEFAULT, traffic=traffic, mode=mode)
+                                rows, _DEFAULT, traffic=traffic, mode=mode,
+                                spec=specs.adamw_spec(p2, g2, m2, v2))
     return _adamw(p, g, m, v, lr, b1, b2, eps, wd, bc1, bc2, cfg, mode)

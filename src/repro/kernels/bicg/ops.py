@@ -35,5 +35,7 @@ def bicg(a: jax.Array, r: jax.Array, p: jax.Array,
     m, n = a.shape
     traffic = Traffic(rows=m, cols=n, dtype=a.dtype, read_arrays=2)
     cfg = common.resolve_config("bicg", a.shape, a.dtype, config, m,
-                                _DEFAULT, traffic=traffic, mode=mode)
+                                _DEFAULT, traffic=traffic, mode=mode,
+                                spec=(specs.bicg_q_spec(a, p),
+                                      specs.bicg_s_spec(a, r)))
     return _bicg(a, r, p, cfg, mode)

@@ -75,26 +75,30 @@ def pad_axis(x: jax.Array, axis: int, multiple: int, value=0) -> jax.Array:
 
 
 def effective_config(config: StridingConfig | None, rows: int | None,
-                     default: StridingConfig) -> StridingConfig:
+                     default: StridingConfig,
+                     align: int = 1) -> StridingConfig:
     """Clamp a config's stride_unroll to divide `rows` (``rows=None`` =
-    no divisibility constraint — the kernel pads+crops instead)."""
+    no divisibility constraint — the kernel pads+crops instead).  With
+    ``align > 1`` each of the D streams must also hold a multiple of
+    ``align`` rows where some D allows it (see :func:`row_align`), so 8
+    decode rows run as D=1."""
     cfg = config or default
     if rows is None:
         return cfg
     d = cfg.stride_unroll
-    while rows % d != 0:
+    while d > 1 and (rows % d or (rows // d) % align):
         d -= 1
     if d != cfg.stride_unroll:
         cfg = cfg.replace(stride_unroll=max(d, 1))
     return cfg
 
 
-# planner results are pure in (kernel, shape, dtype, backend) — memoized
-# so a hot loop (e.g. adamw per tensor per step) doesn't re-rank on every
-# call.  The backend is part of the key: the DMA model's parameters are
-# per-machine, so a result planned under one backend must not leak into
-# another.  The tune-cache lookup stays per-call: a fresh autotune write
-# must win.
+# planner results are pure in (kernel, shape, dtype, backend, spec) —
+# memoized so a hot loop (e.g. adamw per tensor per step) doesn't re-rank
+# on every call.  The backend is part of the key: the DMA model's
+# parameters are per-machine, so a result planned under one backend must
+# not leak into another.  The tune-cache lookup stays per-call: a fresh
+# autotune write must win.
 _plan_memo: dict[tuple, StridingConfig | None] = {}
 
 
@@ -102,6 +106,20 @@ def reset_plan_memo() -> None:
     """Drop memoized planner results (tests repoint backends / DMA-model
     env knobs; pair with ``tunecache.reset_default_cache()``)."""
     _plan_memo.clear()
+
+
+def row_align(spec, mode: str | None) -> int:
+    """Rows each of the D streams must hold a multiple of.  In
+    ``pallas`` mode that is Mosaic's tiling rule for the kernel's spec
+    (or tuple of specs), see ``codegen.transforms.row_align``; without
+    a spec, the 8-row sublane tile every block needs.  The interpreter
+    and the oracle take any row count."""
+    if mode != "pallas":
+        return 1
+    if spec is None:
+        return 8
+    from repro.codegen.transforms import row_align as spec_row_align
+    return spec_row_align(spec)
 
 
 def resolve_config(kernel: str, shape, dtype, config, rows: int | None,
@@ -116,7 +134,10 @@ def resolve_config(kernel: str, shape, dtype, config, rows: int | None,
     to the next call, which a jit-cached trace would freeze out.  The
     result is clamped so stride_unroll divides ``rows``; pass
     ``rows=None`` when the kernel's pad+crop makes any D valid (§5.1.1
-    loop-blocked 1-D nests).
+    loop-blocked 1-D nests).  ``spec`` (the kernel's ``TraversalSpec``,
+    or a tuple for a composite) screens the planner's candidates and,
+    in ``pallas`` mode, sets how many rows each stream must hold
+    (:func:`row_align`): D is clamped further to meet it.
 
     With telemetry on, every call emits one ``kernel.resolve`` event
     recording which source won and the resolved config, plus
@@ -130,7 +151,10 @@ def resolve_config(kernel: str, shape, dtype, config, rows: int | None,
         if config is not None:
             source = "tuned"
         elif traffic is not None:
-            key = (kernel, tuple(shape), str(jnp.dtype(dtype)),
+            names = tuple(s.name for s in (
+                spec if isinstance(spec, tuple) else (spec,))
+                if s is not None)
+            key = (kernel, tuple(shape), str(jnp.dtype(dtype)), names,
                    jax.default_backend())
             if key in _plan_memo:
                 config = _plan_memo[key]
@@ -145,7 +169,8 @@ def resolve_config(kernel: str, shape, dtype, config, rows: int | None,
                 obs.counter("kernel.plan_memo.miss", kernel=kernel)
             if config is not None:
                 source = "planned"
-    cfg = effective_config(config, rows, default)
+    align = row_align(spec, mode)
+    cfg = effective_config(config, rows, default, align)
     if source != "explicit":
         # a config the guarded fallback chain watched fail must never be
         # re-resolved: the tuned source already skips quarantined entries
@@ -155,7 +180,7 @@ def resolve_config(kernel: str, shape, dtype, config, rows: int | None,
         qkey = tunecache.cache_key(kernel, shape, dtype, mode=mode)
         if cache.is_quarantined(qkey, cfg):
             cfg = _next_unquarantined(cache, qkey, cfg, rows, default,
-                                      traffic, spec=spec)
+                                      traffic, spec=spec, align=align)
             source = "quarantine_alt"
             obs.counter("kernel.quarantine_skip", kernel=kernel)
     if obs.enabled():
@@ -168,7 +193,7 @@ def resolve_config(kernel: str, shape, dtype, config, rows: int | None,
 
 def _next_unquarantined(cache, qkey: str, failed: StridingConfig,
                         rows: int | None, default: StridingConfig,
-                        traffic, spec=None) -> StridingConfig:
+                        traffic, spec, align: int) -> StridingConfig:
     """Best non-quarantined alternative: next planner-ranked configs,
     then the static default, then single-strided (D=1 streams one
     contiguous run — the most conservative point in the space, kept as
@@ -184,7 +209,7 @@ def _next_unquarantined(cache, qkey: str, failed: StridingConfig,
             cands = []
     cands += [default, SINGLE_STRIDED]
     for cand in cands:
-        cand = effective_config(cand, rows, cand)
+        cand = effective_config(cand, rows, cand, align)
         if not cache.is_quarantined(qkey, cand):
             return cand
     return SINGLE_STRIDED
@@ -226,9 +251,12 @@ def classify_failure(exc: BaseException) -> str:
 
 
 def _fallback_tiers(cache, qkey: str, failed: StridingConfig,
-                    mode: str, rows: int | None, traffic, spec=None):
+                    mode: str, rows: int | None, traffic, spec):
     """The degradation chain after ``failed`` crashed in ``mode``:
-    next-ranked planner configs (same mode) → interpret → ref oracle."""
+    next-ranked planner configs (same mode) → interpret → ref oracle.
+    On a TPU backend the interpret tier is left out: the interpreter
+    there is orders of magnitude slower than either kernel or oracle."""
+    align = row_align(spec, mode)
     tiers = []
     if traffic is not None:
         from repro.core.planner import rank_configs
@@ -240,7 +268,7 @@ def _fallback_tiers(cache, qkey: str, failed: StridingConfig,
         seen = {(failed.stride_unroll, failed.portion_unroll,
                  failed.block_rows)}
         for cand in ranked:
-            cand = effective_config(cand, rows, cand)
+            cand = effective_config(cand, rows, cand, align)
             key = (cand.stride_unroll, cand.portion_unroll,
                    cand.block_rows)
             if key in seen or cache.is_quarantined(qkey, cand):
@@ -249,7 +277,7 @@ def _fallback_tiers(cache, qkey: str, failed: StridingConfig,
             tiers.append(("alt_config", cand, mode))
             if len(tiers) >= 2:
                 break
-    if mode == "pallas":
+    if mode == "pallas" and jax.default_backend() != "tpu":
         # interpret escapes backend/VMEM failures (the body runs in
         # Python) while still exercising the generated lowering
         tiers.append(("interpret", failed, "interpret"))
@@ -271,11 +299,12 @@ def guarded_run(kernel: str, run, cfg: StridingConfig, mode: str, *,
     no tier below it: a ref failure is an oracle bug and re-raises
     untouched.
 
-    ``spec`` rides into the planner's candidate ranking so alternative
-    tiers are themselves pre-screened by the static verifier — a
-    statically-rejected config (failure class ``analysis``) degrades
-    straight past the emitting tiers to the ref oracle with ZERO
-    ``pallas_call`` construction attempts.
+    ``spec`` rides into the planner's candidate ranking (and sets the
+    rows each stream of an alternative holds, :func:`row_align`) so
+    alternative tiers are themselves pre-screened by the static
+    verifier — a statically-rejected config (failure class
+    ``analysis``) degrades straight past the emitting tiers to the ref
+    oracle with ZERO ``pallas_call`` construction attempts.
 
     The ``lower`` fault-injection site fires here (non-ref modes), so
     ``REPRO_FAULTS=lower:<kernel>`` forces any guarded kernel down the
@@ -302,8 +331,7 @@ def guarded_run(kernel: str, run, cfg: StridingConfig, mode: str, *,
         cache.quarantine(qkey, cfg, failure)
         obs.counter("kernel.fallback.count", kernel=kernel)
         for tier, tcfg, tmode in _fallback_tiers(cache, qkey, cfg, mode,
-                                                 rows, traffic,
-                                                 spec=spec):
+                                                 rows, traffic, spec):
             try:
                 out = attempt(tcfg, tmode)
             except (KeyboardInterrupt, SystemExit):

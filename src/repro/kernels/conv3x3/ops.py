@@ -30,5 +30,6 @@ def conv3x3(x: jax.Array, w: jax.Array,
     mode = mode or common.kernel_mode()
     h_out = max(x.shape[0] - 2, 1)
     cfg = common.resolve_config("conv3x3", x.shape, x.dtype, config, h_out,
-                                _DEFAULT, mode=mode)
+                                _DEFAULT, mode=mode,
+                                spec=specs.conv3x3_spec(x))
     return _conv3x3(x, w, cfg, mode)

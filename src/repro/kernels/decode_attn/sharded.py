@@ -29,7 +29,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
+from repro import obs
 from repro.kernels.decode_attn import ops
 
 __all__ = ["merge_partials", "decode_attn_sharded",
@@ -121,8 +121,8 @@ def decode_attn_shard_map(q: jax.Array, kc: jax.Array, vc: jax.Array,
         num = jax.lax.psum(w[..., None] * o.astype(jnp.float32), axis)
         return (num / den[..., None]).astype(qs.dtype)
 
-    fn = compat.shard_map(
-        body, mesh,
+    fn = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(P(), P(None, axis, None, None),
                   P(None, axis, None, None), P()),
         out_specs=P(), check_vma=False)
@@ -143,7 +143,11 @@ def dispatch(q: jax.Array, kc: jax.Array, vc: jax.Array, kv_len=None,
     axis = getattr(ctx, "tp_axis", "model") if ctx is not None else "model"
     if (shards > 1 and mesh is not None and axis in mesh.shape
             and int(mesh.shape[axis]) == shards):
+        obs.counter("decode_attn.dispatch", strategy="shard_map",
+                    shards=shards)
         return decode_attn_shard_map(q, kc, vc, kv_len=kv_len, mesh=mesh,
                                      axis=axis, config=config, mode=mode)
+    obs.counter("decode_attn.dispatch", strategy="static_split",
+                shards=shards)
     return decode_attn_sharded(q, kc, vc, kv_len=kv_len, shards=shards,
                                config=config, mode=mode)

@@ -13,7 +13,9 @@ each block's (max, rescaled Σ softmax·V, rescaled Σ w) partial state
 merges numerically-stably across the D merged streams and grid steps
 and K/V are each read exactly once.  The combinator's finalize ALSO
 emits the per-row log-sum-exp as a second native output (its own
-``Hq``-wide access map).
+``Hq``-wide access map).  q arrives as ``[b, Hq, dh]`` and the body
+works one KV head at a time in 2-D matmuls, keeping the combinator's
+``[Hq, …]`` state layout: the forms Mosaic compiles.
 
 ``masked=True`` adds a fourth read: a per-position validity row stream
 ``M`` (1.0 = attend, 0.0 = masked) riding the same D-stream split as
@@ -43,40 +45,37 @@ def decode_spec(hkv: int, dh: int, masked: bool = False):
     finalizes ``num / den`` into the output — one K sweep, one V sweep.
     """
 
-    def heads(block, rows):
-        return block.reshape(block.shape[0], rows, hkv, dh)
-
-    def scores(env, scale):
-        kb = env["K"]
-        b, rows = kb.shape[0], kb.shape[1]
-        hq = env["q"].shape[-1] // dh
-        g = hq // hkv
-        q4 = env["q"].reshape(b, hkv, g, dh).astype(jnp.float32)
-        k4 = heads(kb, rows).astype(jnp.float32)
-        s4 = jnp.einsum("bhgd,bshd->bhgs", q4, k4) * scale
-        return s4.reshape(b, hq, rows)
-
-    def spec(kc2, vc2, q2, *mask):
+    def spec(kc2, vc2, q3, *mask):
         b, s, e = kc2.shape
-        hq = q2.shape[-1] // dh
+        hq = q3.shape[1]
         g = hq // hkv
         scale = 1.0 / (dh ** 0.5)
 
         def body(env):
-            sc = scores(env, scale)                       # (B, Hq, rows)
-            if masked:
-                sc = jnp.where(env["M"][:, None, :] > 0.5, sc, -1e30)
-            m = sc.max(axis=-1)                           # (B, Hq)
-            w = jnp.exp(sc - m[..., None])
-            b_, rows = w.shape[0], w.shape[-1]
-            v4 = heads(env["V"], rows).astype(jnp.float32)
-            pv = jnp.einsum("bhgs,bshd->bhgd",
-                            w.reshape(b_, hkv, g, rows), v4)
-            return (m, pv.reshape(b_, hq * dh), w.sum(axis=-1))
+            # one KV head at a time, as 2-D matmuls with one batch dim:
+            # the forms Mosaic lowers.  State comes out [b, Hq, …], so
+            # nothing is reshaped across lanes
+            ms, nums, dens = [], [], []
+            for h in range(hkv):
+                lanes = slice(h * dh, (h + 1) * dh)
+                kh = env["K"][:, :, lanes].astype(jnp.float32)
+                vh = env["V"][:, :, lanes].astype(jnp.float32)
+                qh = env["q"][:, h * g:(h + 1) * g, :].astype(jnp.float32)
+                sc = jnp.einsum("bgd,bsd->bgs", qh, kh) * scale
+                if masked:
+                    sc = jnp.where(env["M"][:, None, :] > 0.5, sc, -1e30)
+                m = sc.max(axis=-1, keepdims=True)            # (b, g, 1)
+                w = jnp.exp(sc - m)
+                ms.append(m)
+                nums.append(jnp.einsum("bgs,bsd->bgd", w, vh))
+                dens.append(w.sum(axis=-1, keepdims=True))
+            return (jnp.concatenate(ms, axis=1),
+                    jnp.concatenate(nums, axis=1),
+                    jnp.concatenate(dens, axis=1))
 
         reads = (Access("K", ("b", "s", "e")),
                  Access("V", ("b", "s", "e")),
-                 Access("q", ("b", "f")))
+                 Access("q", ("b", "h", "x")))
         if masked:
             reads += (Access("M", ("b", "s")),)
 
@@ -84,13 +83,12 @@ def decode_spec(hkv: int, dh: int, masked: bool = False):
             name="decode_attn_masked" if masked else "decode_attn_spec",
             axes=(Axis("b", b, kind="batch"),
                   Axis("s", s, kind="reduction"), Axis("e", e),
-                  Axis("f", hq * dh), Axis("z", hq * dh),
-                  Axis("h", hq)),
+                  Axis("h", hq), Axis("x", dh)),
             reads=reads,
-            # two writes, two access maps: the attention row (Hq·dh
-            # lanes) and the Hq-wide log-sum-exp row statistic — both
-            # finalized from ONE accumulated online-softmax state
-            writes=(Access("o", ("b", "z")), Access("lse", ("b", "h"))),
+            # two writes, two access maps: the [Hq, dh] attention rows
+            # and the Hq-wide log-sum-exp statistic — both finalized
+            # from ONE accumulated online-softmax state
+            writes=(Access("o", ("b", "h", "x")), Access("lse", ("b", "h"))),
             body=body, out_dtype=(jnp.float32, jnp.float32),
             reduce=OnlineSoftmax(groups=hq, vwidth=dh, with_lse=True),
             full_width=True,

@@ -34,5 +34,6 @@ def doitgen(a: jax.Array, c4: jax.Array,
     traffic = Traffic(rows=m, cols=s, dtype=a.dtype, read_arrays=1,
                       write_arrays=1, resident_bytes=s * p * 4)
     cfg = common.resolve_config("doitgen", a.shape, a.dtype, config, m,
-                                _DEFAULT, traffic=traffic, mode=mode)
+                                _DEFAULT, traffic=traffic, mode=mode,
+                                spec=specs.doitgen_spec(a, c4))
     return _doitgen(a, c4, cfg, mode)

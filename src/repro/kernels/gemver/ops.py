@@ -36,8 +36,10 @@ def gemver_outer(a, u1, v1, u2, v2, config: StridingConfig | None = None,
     m, n = a.shape
     traffic = Traffic(rows=m, cols=n, dtype=a.dtype, read_arrays=1,
                       write_arrays=1)
-    cfg = common.resolve_config("gemver_outer", a.shape, a.dtype, config, m,
-                                _DEFAULT, traffic=traffic, mode=mode)
+    cfg = common.resolve_config(
+        "gemver_outer", a.shape, a.dtype, config, m, _DEFAULT,
+        traffic=traffic, mode=mode,
+        spec=specs.gemver_outer_spec(a, u1, v1, u2, v2))
     return _outer(a, u1, v1, u2, v2, cfg, mode)
 
 

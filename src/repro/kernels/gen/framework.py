@@ -49,6 +49,8 @@ from repro.kernels.rmsnorm import ref as _rms_ref
 from repro.kernels.rmsnorm.specs import rmsnorm_spec
 from repro.registry.base import KernelSpec, register
 
+_S = jax.ShapeDtypeStruct   # specs built on placeholders (resolve, registry)
+
 __all__ = ["decode_attn_gen", "rmsnorm_gen", "adamw_update_gen",
            # family specs re-exported for spec-level consumers
            "adamw_spec", "rmsnorm_spec"]
@@ -61,8 +63,8 @@ def _decode_run(q, kc, vc, hkv, dh, config, mode):
     b, hq = q.shape[0], q.shape[1]
     s, e = kc.shape[1], hkv * dh
     kc2, vc2 = kc.reshape(b, s, e), vc.reshape(b, s, e)
-    q2 = q.reshape(b, hq * dh)
-    out, lse = run_spec(_decode_spec(hkv, dh), (kc2, vc2, q2), config, mode)
+    q3 = q.reshape(b, hq, dh)
+    out, lse = run_spec(_decode_spec(hkv, dh), (kc2, vc2, q3), config, mode)
     return out.reshape(b, hq, dh).astype(q.dtype), lse.reshape(b, hq)
 
 
@@ -75,13 +77,17 @@ def decode_attn_gen(q, kc, vc, config=None, mode=None, with_lse=False):
     mode = _mode(mode)
     s, hkv, dh = kc.shape[1], kc.shape[2], kc.shape[3]
     traffic = Traffic(rows=s, cols=hkv * dh, dtype=kc.dtype, read_arrays=2)
+    e = hkv * dh
+    spec = _decode_spec(hkv, dh)(_S((q.shape[0], s, e), kc.dtype),
+                                 _S((q.shape[0], s, e), kc.dtype),
+                                 _S((q.shape[0], q.shape[1], dh), q.dtype))
     cfg = _resolve("decode_attn_gen", kc, config, mode, s,
-                   StridingConfig(4, 1), traffic)
+                   StridingConfig(4, 1), traffic, spec)
     out, lse = _guarded(
         "decode_attn_gen",
         lambda c, km: _decode_run(q, kc, vc, hkv=hkv, dh=dh, config=c,
                                   mode=km),
-        kc, cfg, mode, s, traffic)
+        kc, cfg, mode, s, traffic, spec)
     return (out, lse) if with_lse else out
 
 
@@ -106,12 +112,13 @@ def rmsnorm_gen(x, w, eps=1e-6, config=None, mode=None,
     traffic = Traffic(rows=max(t, 1), cols=x.shape[-1], dtype=x.dtype,
                       read_arrays=1, write_arrays=1,
                       resident_bytes=x.shape[-1] * 4)
+    spec = rmsnorm_spec(_S((max(t, 1), x.shape[-1]), x.dtype), w)
     cfg = _resolve("rmsnorm_gen", x, config, mode, max(t, 1),
-                   StridingConfig(4, 1), traffic)
+                   StridingConfig(4, 1), traffic, spec)
     out, inv = _guarded(
         "rmsnorm_gen",
         lambda c, km: _rms_run(x, w, eps, config=c, mode=km),
-        x, cfg, mode, max(t, 1), traffic)
+        x, cfg, mode, max(t, 1), traffic, spec)
     return (out, inv) if with_inv_rms else out
 
 
@@ -146,8 +153,6 @@ def adamw_update_gen(p, g, m, v, lr, b1=0.9, b2=0.999, eps=1e-8, wd=0.0,
 
 # ---------------------------------------------------------- registry
 
-_S = jax.ShapeDtypeStruct   # traversal rows build IR on placeholders
-
 _DA_SIZES = {"b": 1, "s": 256, "hq": 4, "hkv": 2, "dh": 64}
 _DA_ALIASED = {"b": 1, "s": 512, "hq": 4, "hkv": 2, "dh": 64}
 
@@ -176,7 +181,7 @@ register(KernelSpec(
     traversal=lambda s, dt: _decode_spec(s["hkv"], s["dh"])(
         _S((s["b"], s["s"], s["hkv"] * s["dh"]), dt),
         _S((s["b"], s["s"], s["hkv"] * s["dh"]), dt),
-        _S((s["b"], s["hq"] * s["dh"]), dt)),
+        _S((s["b"], s["hq"], s["dh"]), dt)),
     cache_shape=lambda s: (s["b"], s["s"], s["hkv"], s["dh"]),
     bench_sizes={"b": 8, "s": 8192, "hq": 32, "hkv": 8, "dh": 128},
     rtol=2e-5, atol=2e-5, tags=("framework", "gen")))

@@ -58,15 +58,16 @@ __all__ = ["bicg_gen", "gemver_outer_gen", "gemver_sum_gen",
 
 
 def _resolve(kernel: str, lead, config, mode, rows: int,
-             default: StridingConfig, traffic):
+             default: StridingConfig, traffic, spec=None):
     """Composite ops resolve one config under their own name (explicit >
     tune-cache > planner > default) and fuse every inner generated spec
     into a single jitted program — one dispatch, like the hand-written
-    fused kernels."""
+    fused kernels.  ``spec`` is the inner spec, or a tuple of them."""
     from repro.kernels import common
     return common.resolve_config(
         kernel, lead.shape, lead.dtype, config, rows, default,
-        traffic=(None if config is not None else traffic), mode=mode)
+        traffic=(None if config is not None else traffic), mode=mode,
+        spec=spec)
 
 
 def _mode(mode):
@@ -76,14 +77,15 @@ def _mode(mode):
     return mode
 
 
-def _guarded(kernel: str, run, lead, cfg, mode, rows, traffic):
+def _guarded(kernel: str, run, lead, cfg, mode, rows, traffic, spec=None):
     """Composite wrappers dispatch through the same guarded fallback
     chain as ``make_kernel_op`` kernels: a failed lowering degrades
     alt-config → interpret → ref and quarantines the failing config
     (see ``common.guarded_run``)."""
     from repro.kernels import common
     return common.guarded_run(kernel, run, cfg, mode, shape=lead.shape,
-                              dtype=lead.dtype, rows=rows, traffic=traffic)
+                              dtype=lead.dtype, rows=rows, traffic=traffic,
+                              spec=spec)
 
 
 # ---------------------------------------------------------------- bicg
@@ -99,11 +101,12 @@ def bicg_gen(a, r, p, config=None, mode=None):
     mode = _mode(mode)
     m, n = a.shape
     traffic = Traffic(rows=m, cols=n, dtype=a.dtype, read_arrays=2)
+    spec = (bicg_q_spec(a, p), bicg_s_spec(a, r))
     cfg = _resolve("bicg_gen", a, config, mode, m, StridingConfig(4, 2),
-                   traffic)
+                   traffic, spec)
     return _guarded("bicg_gen",
                     lambda c, km: _bicg_run(a, r, p, config=c, mode=km),
-                    a, cfg, mode, m, traffic)
+                    a, cfg, mode, m, traffic, spec)
 
 
 # -------------------------------------------------------------- gemver
@@ -126,12 +129,13 @@ def gemver_mxv1_gen(a, y, x, beta, config=None, mode=None):
     mode = _mode(mode)
     m, n = a.shape
     traffic = Traffic(rows=m, cols=n, dtype=a.dtype, read_arrays=2)
+    spec = gemver_mxv1_spec(a, y)
     cfg = _resolve("gemver_mxv1_gen", a, config, mode, m,
-                   StridingConfig(4, 2), traffic)
+                   StridingConfig(4, 2), traffic, spec)
     return _guarded(
         "gemver_mxv1_gen",
         lambda c, km: _mxv1_run(a, y, x, beta, config=c, mode=km),
-        a, cfg, mode, m, traffic)
+        a, cfg, mode, m, traffic, spec)
 
 
 @functools.partial(jax.jit, static_argnames=("config", "mode"))
@@ -148,12 +152,13 @@ def gemver_mxv1_sum_gen(a, y, x, z, beta, config=None, mode=None):
     mode = _mode(mode)
     m, n = a.shape
     traffic = Traffic(rows=m, cols=n, dtype=a.dtype, read_arrays=2)
+    spec = gemver_mxv1_sum_spec(a, y)
     cfg = _resolve("gemver_mxv1_sum_gen", a, config, mode, m,
-                   StridingConfig(4, 2), traffic)
+                   StridingConfig(4, 2), traffic, spec)
     return _guarded(
         "gemver_mxv1_sum_gen",
         lambda c, km: _mxv1_sum_run(a, y, x, z, beta, config=c, mode=km),
-        a, cfg, mode, m, traffic)
+        a, cfg, mode, m, traffic, spec)
 
 
 # ------------------------------------------------------------- conv3x3
@@ -170,11 +175,12 @@ def conv3x3_gen(x, w, config=None, mode=None):
     h_out = max(x.shape[0] - 2, 1)
     traffic = Traffic(rows=h_out, cols=max(x.shape[1] - 2, 1),
                       dtype=x.dtype, read_arrays=3, write_arrays=1)
+    spec = conv3x3_spec(x)
     cfg = _resolve("conv3x3_gen", x, config, mode, h_out,
-                   StridingConfig(4, 1), traffic)
+                   StridingConfig(4, 1), traffic, spec)
     return _guarded("conv3x3_gen",
                     lambda c, km: _conv_run(x, w, config=c, mode=km),
-                    x, cfg, mode, h_out, traffic)
+                    x, cfg, mode, h_out, traffic, spec)
 
 
 # ------------------------------------------------------------- doitgen

@@ -29,5 +29,5 @@ def jacobi2d(x: jax.Array, config: StridingConfig | None = None,
     mode = mode or common.kernel_mode()
     h_out = max(x.shape[0] - 2, 1)
     cfg = common.resolve_config("jacobi2d", x.shape, x.dtype, config, h_out,
-                                _DEFAULT, mode=mode)
+                                _DEFAULT, mode=mode, spec=specs.jacobi_spec(x))
     return _jacobi2d(x, cfg, mode)

@@ -24,12 +24,13 @@ from repro.kernels.mxv import specs
 _DEFAULT = StridingConfig(stride_unroll=4, portion_unroll=2)
 
 
-def _resolve(kernel, shape, dtype, config, mode, extra_reads=0):
-    m, n = shape
-    traffic = Traffic(rows=m, cols=n, dtype=dtype,
+def _resolve(kernel, spec, a, config, mode, extra_reads=0):
+    m, n = a.shape
+    traffic = Traffic(rows=m, cols=n, dtype=a.dtype,
                       read_arrays=1 + extra_reads)
-    return common.resolve_config(kernel, shape, dtype, config, m,
-                                 _DEFAULT, traffic=traffic, mode=mode)
+    return common.resolve_config(kernel, a.shape, a.dtype, config, m,
+                                 _DEFAULT, traffic=traffic, mode=mode,
+                                 spec=spec)
 
 
 @functools.partial(jax.jit, static_argnames=("config", "mode"))
@@ -41,7 +42,7 @@ def mxv(a: jax.Array, x: jax.Array, config: StridingConfig | None = None,
         mode: str | None = None) -> jax.Array:
     """y = A @ x (paper mxv / gemvermxv2)."""
     mode = mode or common.kernel_mode()
-    cfg = _resolve("mxv", a.shape, a.dtype, config, mode)
+    cfg = _resolve("mxv", specs.mxv_spec(a, x), a, config, mode)
     return _mxv(a, x, cfg, mode)
 
 
@@ -54,5 +55,6 @@ def mxv_t(a: jax.Array, x: jax.Array, config: StridingConfig | None = None,
           mode: str | None = None) -> jax.Array:
     """y = Aᵀ @ x (paper Listing 1: gemvermxv1 / doitgen core)."""
     mode = mode or common.kernel_mode()
-    cfg = _resolve("mxv_t", a.shape, a.dtype, config, mode, extra_reads=1)
+    cfg = _resolve("mxv_t", specs.mxv_t_spec(a, x), a, config, mode,
+                   extra_reads=1)
     return _mxv_t(a, x, cfg, mode)

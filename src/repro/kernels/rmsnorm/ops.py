@@ -35,6 +35,8 @@ def rmsnorm(x: jax.Array, w: jax.Array, eps: float = 1e-6,
     t = 1
     for s in x.shape[:-1]:
         t *= s
+    x2 = jax.ShapeDtypeStruct((max(t, 1), x.shape[-1]), x.dtype)
     cfg = common.resolve_config("rmsnorm", x.shape, x.dtype, config,
-                                max(t, 1), _DEFAULT, mode=mode)
+                                max(t, 1), _DEFAULT, mode=mode,
+                                spec=specs.rmsnorm_spec(x2, w))
     return _rmsnorm(x, w, eps, cfg, mode)

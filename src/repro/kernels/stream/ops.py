@@ -25,12 +25,14 @@ from repro.kernels.stream import specs
 _DEFAULT = StridingConfig(stride_unroll=4, portion_unroll=2)
 
 
-def _resolve(kernel, x_shape, dtype, config, mode, read_arrays, write_arrays):
+def _resolve(kernel, x_shape, dtype, config, mode, read_arrays, write_arrays,
+             spec=None):
     rows, cols = x_shape
     traffic = Traffic(rows=rows, cols=cols, dtype=dtype,
                       read_arrays=read_arrays, write_arrays=write_arrays)
     return common.resolve_config(kernel, x_shape, dtype, config, rows,
-                                 _DEFAULT, traffic=traffic, mode=mode)
+                                 _DEFAULT, traffic=traffic, mode=mode,
+                                 spec=spec)
 
 
 @functools.partial(jax.jit, static_argnames=("config", "mode"))
@@ -45,6 +47,7 @@ def stream_read(x: jax.Array, config: StridingConfig | None = None,
                 mode: str | None = None) -> jax.Array:
     """Per-stream checksums of a [rows, cols] array (paper §4.3 reads)."""
     mode = mode or common.kernel_mode()
+    # no spec: its [D, seg·cols] operand depends on the resolved D
     cfg = _resolve("stream_read", x.shape, x.dtype, config, mode, 1, 0)
     return _read(x, cfg, mode)
 
@@ -58,7 +61,8 @@ def stream_copy(x: jax.Array, config: StridingConfig | None = None,
                 mode: str | None = None) -> jax.Array:
     """y = x (paper §4.6 copy)."""
     mode = mode or common.kernel_mode()
-    cfg = _resolve("stream_copy", x.shape, x.dtype, config, mode, 1, 1)
+    cfg = _resolve("stream_copy", x.shape, x.dtype, config, mode, 1, 1,
+                   specs.copy_spec(x))
     return _copy(x, cfg, mode)
 
 
@@ -76,7 +80,8 @@ def stream_init(shape: tuple[int, int], value=0.0, dtype=jnp.float32,
     """Fill (paper 'init' kernel, Table 1): a writes-only spec — zero
     read streams, D strided store positions."""
     mode = mode or common.kernel_mode()
-    cfg = _resolve("stream_init", shape, dtype, config, mode, 0, 1)
+    cfg = _resolve("stream_init", shape, dtype, config, mode, 0, 1,
+                   specs.init_spec(shape, dtype))
     return _init(tuple(shape), value, dtype, cfg, mode)
 
 
@@ -92,5 +97,6 @@ def stream_copy_manual(x: jax.Array, config: StridingConfig | None = None,
     ``make_async_copy`` ring (lookahead=1 = the prefetch-off ablation);
     lookahead=2 is the Pallas auto-pipeline's own double-buffer depth."""
     mode = mode or common.kernel_mode()
-    cfg = _resolve("stream_copy_manual", x.shape, x.dtype, config, mode, 1, 1)
+    cfg = _resolve("stream_copy_manual", x.shape, x.dtype, config, mode, 1, 1,
+                   specs.copy_spec(x))
     return _copy_manual(x, cfg, mode)

@@ -1,7 +1,9 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST precede every other import (jax locks the
-# device count at first init).
+if __name__ == "__main__":
+    # 512 host devices for the production mesh, set before any import
+    # (jax fixes the device count when it first initializes); importing
+    # this module sets nothing, so no chip path inherits the flag
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 import argparse  # noqa: E402
 import json  # noqa: E402

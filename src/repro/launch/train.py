@@ -1,9 +1,9 @@
 """Training launcher: ``python -m repro.launch.train --arch yi-9b ...``
 
 On a real multi-host pod this runs under `jax.distributed.initialize()`
-(one process per host; flags below). In this container it runs reduced
-configs on CPU end-to-end: data pipeline → pjit train step → checkpoint
-manager → straggler monitor.
+(one process per host; flags below). With ``--reduced`` every width
+shrinks (``configs.reduced``) so the whole loop — data pipeline → pjit
+train step → checkpoint manager → straggler monitor — runs on a CPU.
 """
 from __future__ import annotations
 
@@ -27,8 +27,9 @@ from repro.train.trainstep import init_state
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="yi-9b")
-    ap.add_argument("--reduced", action="store_true", default=True,
-                    help="reduced config (CPU-sized)")
+    ap.add_argument("--reduced", action="store_true",
+                    help="shrink every width (configs.reduced) for CPU "
+                         "smoke runs")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)

@@ -216,7 +216,7 @@ def attn_decode(p, x, cfg: ModelConfig, cache, pos: jax.Array, rope,
     vector (ragged continuous batching — each row writes its own cache
     position and attends to its own ``kv_len``); rope built for pos.
     ``shards > 1`` runs the sequence-sharded flash-decode combine (see
-    ``kernels.decode_attn.sharded``).
+    ``kernels.decode_attn.sharded``) over ``ctx``'s mesh.
     """
     q, k, v = _qkv(p, x, cfg, rope, ctx)
     pos = jnp.asarray(pos, jnp.int32)

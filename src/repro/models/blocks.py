@@ -232,7 +232,8 @@ def init_stack_cache(cfg: ModelConfig, batch: int, max_len: int, dtype):
 
 def _layer_decode(p, c, x, cfg, desc, rope, pos, ctx, cross_kv=None,
                   shards: int = 1):
-    h = common.rms_norm(x, p["norm1"].astype(x.dtype), cfg.norm_eps)
+    nmesh = common.kernel_mesh(ctx, shards)
+    h = common.rms_norm(x, p["norm1"].astype(x.dtype), cfg.norm_eps, nmesh)
     newc = {}
     if desc.mixer == "attn":
         a, newc["attn"] = attention.attn_decode(p["attn"], h, cfg,
@@ -243,10 +244,12 @@ def _layer_decode(p, c, x, cfg, desc, rope, pos, ctx, cross_kv=None,
                                                c["mamba"])
     x = x + a
     if desc.cross and cross_kv is not None:
-        h = common.rms_norm(x, p["norm_x"].astype(x.dtype), cfg.norm_eps)
+        h = common.rms_norm(x, p["norm_x"].astype(x.dtype), cfg.norm_eps,
+                            nmesh)
         x = x + attention.cross_attn_forward(p["cross"], h, cfg, cross_kv)
     if desc.ffn != "none":
-        h = common.rms_norm(x, p["norm2"].astype(x.dtype), cfg.norm_eps)
+        h = common.rms_norm(x, p["norm2"].astype(x.dtype), cfg.norm_eps,
+                            nmesh)
         if desc.ffn == "moe":
             f, _ = moe.moe_forward(p["moe"], h, cfg, ctx)
         else:

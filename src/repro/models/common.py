@@ -97,10 +97,28 @@ def embed_init(key, vocab: int, d: int, dtype=jnp.float32):
             * (1.0 / math.sqrt(d))).astype(dtype)
 
 
-def rms_norm(x, scale, eps):
+def kernel_mesh(ctx: Optional[MeshCtx], shards: int):
+    """The mesh on which a decode step's Pallas kernels run once per
+    device, inside ``shard_map``: under KV-sharded serving
+    (``shards > 1``) the residual stream is replicated over ``ctx``'s
+    mesh, and XLA cannot partition a Pallas kernel.  None otherwise.
+    The XLA matmuls around the kernels keep ``ctx``'s tensor-parallel
+    sharding either way."""
+    return ctx.mesh if ctx is not None and shards > 1 else None
+
+
+def rms_norm(x, scale, eps, mesh=None):
     """Fused multi-strided kernel on TPU; jnp ref elsewhere (see
-    kernels/common.kernel_mode)."""
-    return rmsnorm_ops.rmsnorm(x, scale, eps=eps)
+    kernels/common.kernel_mode).  With a multi-device ``mesh`` (see
+    :func:`kernel_mesh`) the kernel runs once per device inside
+    ``shard_map``: XLA cannot partition a Pallas kernel, even one whose
+    operands are replicated."""
+    if mesh is None or mesh.size == 1:
+        return rmsnorm_ops.rmsnorm(x, scale, eps=eps)
+    rep = jax.sharding.PartitionSpec()
+    return jax.shard_map(
+        lambda x, s: rmsnorm_ops.rmsnorm(x, s, eps=eps), mesh=mesh,
+        in_specs=(rep, rep), out_specs=rep, check_vma=False)(x, scale)
 
 
 def make_rope(positions: jax.Array, head_dim: int, theta: float,

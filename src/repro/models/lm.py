@@ -168,7 +168,7 @@ class CausalLM:
         x, newcache = blocks.stack_decode(params["blocks"], cache, x, cfg,
                                           rope, pos, ctx, shards=shards)
         x = common.rms_norm(x, params["final_norm"].astype(x.dtype),
-                            cfg.norm_eps)
+                            cfg.norm_eps, common.kernel_mesh(ctx, shards))
         return (_head_logits(params, x, cfg)[:, 0, :cfg.vocab_size],
                 newcache)
 

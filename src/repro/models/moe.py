@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ModelConfig, MoEConfig
 from repro.models import common, ffn
 
@@ -167,7 +166,7 @@ def moe_ep(p, x, cfg: ModelConfig, ctx: common.MeshCtx):
     # batch=1 decode: replicate the batch across dp (EP still over tp)
     baxes = ctx.batch_axes(x.shape[0])
     bspec = baxes if baxes else None
-    y, aux = compat.shard_map(
+    y, aux = jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P(bspec, None, None), P(None, None),
                   P(ctx.tp_axis, None, None), P(ctx.tp_axis, None, None),

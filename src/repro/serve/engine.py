@@ -44,6 +44,7 @@ import numpy as np
 
 from repro import obs
 from repro.runtime.fault_tolerance import HeartbeatRegistry, StepMonitor
+from repro.serve.sharded import place_kv_cache
 
 
 @dataclasses.dataclass(frozen=True)
@@ -178,6 +179,14 @@ class ServingEngine:
                       waited_s=time.perf_counter() - req.submitted_at)
         self._retire(req, deadline_exceeded=True)
 
+    def new_cache(self):
+        """A fresh decode cache for every slot.  KV-sharded over a mesh
+        (``shards > 1``), its K/V lie split along the sequence axis."""
+        cache = self.model.init_cache(self.cfg.slots, self.cfg.max_len)
+        if self.ctx is not None and self.cfg.shards > 1:
+            cache = place_kv_cache(cache, self.ctx)
+        return cache
+
     def _admit(self) -> None:
         """Fill free slots: per-slot prefill via teacher-forced decode of
         the prompt (single compiled step reused; avoids a second compiled
@@ -188,7 +197,7 @@ class ServingEngine:
         it."""
         cfg = self.cfg
         if self.cache is None:
-            self.cache = self.model.init_cache(cfg.slots, cfg.max_len)
+            self.cache = self.new_cache()
         for i in range(cfg.slots):
             while self.slots[i] is None and self.queue:
                 req = self.queue.popleft()
@@ -238,7 +247,9 @@ class ServingEngine:
             s0 = time.perf_counter()
             faults.sleep_if("serve_slow", f"slot{i}")   # injected stall
             stalls.append(time.perf_counter() - s0)
-        pos = jnp.asarray(self.lengths, jnp.int32)
+        # a copy: on the CPU jnp.asarray may alias the numpy buffer that
+        # the length bump below mutates while the step can still run
+        pos = jnp.array(self.lengths, jnp.int32)
         logits, self.cache = self._decode(self.params, jnp.asarray(toks),
                                           self.cache, pos)
         nxt = np.asarray(jnp.argmax(logits, axis=-1))  # sync = step edge

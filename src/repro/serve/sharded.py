@@ -17,7 +17,7 @@ import numpy as np
 
 from repro.models.common import MeshCtx
 
-__all__ = ["resolve_serving_mesh", "serving_ctx"]
+__all__ = ["resolve_serving_mesh", "serving_ctx", "place_kv_cache"]
 
 
 def resolve_serving_mesh(shards: int):
@@ -40,3 +40,19 @@ def serving_ctx(shards: int) -> Optional[MeshCtx]:
     if mesh is None:
         return None
     return MeshCtx(mesh=mesh, dp_axes=(), tp_axis="model")
+
+
+def place_kv_cache(cache, ctx: MeshCtx):
+    """Lay a fresh decode cache out over ``ctx``'s mesh: attention K/V
+    leaves ``[layers, slots, seq, Hkv, dh]`` split their sequence axis
+    over the TP axis (each device holds its slice, which its shard of
+    the flash-decode combine reads); any other state is replicated."""
+    P = jax.sharding.PartitionSpec
+
+    def place(path, leaf):
+        names = [getattr(k, "key", None) for k in path]
+        spec = (P(None, None, ctx.tp_axis)
+                if "attn" in names and names[-1] in ("k", "v") else P())
+        return jax.device_put(leaf, jax.sharding.NamedSharding(ctx.mesh,
+                                                               spec))
+    return jax.tree_util.tree_map_with_path(place, cache)

@@ -9,7 +9,6 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.models.common import MeshCtx
 from repro.train import optimizer as opt
@@ -101,7 +100,7 @@ def make_compressed_train_step(model, ocfg: opt.AdamWConfig, mesh,
         # inside the shard_map the pod axis is Manual: the model's
         # sharding constraints must target the context ABSTRACT mesh
         # (pod=Manual), not the concrete one, and only use (data, model)
-        ctx = MeshCtx(mesh=compat.abstract_mesh(mesh),
+        ctx = MeshCtx(mesh=jax.sharding.get_abstract_mesh(),
                       dp_axes=("data",), tp_axis="model")
 
         def loss_fn(params, batch):
@@ -138,11 +137,11 @@ def make_compressed_train_step(model, ocfg: opt.AdamWConfig, mesh,
             lambda a: P("pod", *([None] * (a.ndim - 1))), batch)
         out_specs = (state_spec, specs_like({"loss": 0, "lr": 0,
                                              "grad_norm": 0}, P()))
-        return compat.shard_map(inner, mesh=mesh,
-                                in_specs=(state_spec, batch_spec),
-                                out_specs=out_specs,
-                                axis_names={"pod"}, check_vma=False)(state,
-                                                                     batch)
+        return jax.shard_map(inner, mesh=mesh,
+                             in_specs=(state_spec, batch_spec),
+                             out_specs=out_specs,
+                             axis_names={"pod"}, check_vma=False)(state,
+                                                                  batch)
 
     return train_step
 

@@ -235,27 +235,32 @@ def draw_case(draw: Draw) -> Case:
 
     if kind == "osm_lse":
         # combinator-under-blocking with distinct write maps: the
-        # paired-state online softmax emits (weighted average, lse) from
-        # one accumulated state; draw_config's block_rows splits the
-        # row grid so partial states merge across steps too
+        # paired-state online softmax emits (weighted average, lse) per
+        # softmax group from one accumulated [groups, …] state;
+        # draw_config's block_rows splits the row grid so partial
+        # states merge across steps too
         x = _arr((rows, cols), 0)
         v = _arr((rows, cols), 1)
+        g = draw.sample(_divisors(cols))
+        vw = cols // g
 
         def body(env):
-            sc = env["x"].astype(jnp.float32).sum(axis=-1)
-            m = sc.max()[None]
-            w = jnp.exp(sc - m)
-            num = (w[:, None] * env["v"].astype(jnp.float32)).sum(axis=0)
-            return (m, num, w.sum()[None])
+            r = env["x"].shape[0]
+            sc = env["x"].astype(jnp.float32).reshape(r, g, vw).sum(-1).T
+            m = sc.max(axis=-1, keepdims=True)             # (g, 1)
+            w = jnp.exp(sc - m)                            # (g, r)
+            vb = env["v"].astype(jnp.float32).reshape(r, g, vw)
+            num = jnp.einsum("gr,rgv->gv", w, vb)          # (g, vw)
+            return (m, num, w.sum(axis=-1, keepdims=True))
 
         spec = TraversalSpec(
             name="prop_osm_lse",
             axes=(Axis("i", rows, kind="reduction"), Axis("j", cols),
-                  Axis("h", 1)),
+                  Axis("h", g), Axis("c", vw)),
             reads=(Access("x", ("i", "j")), Access("v", ("i", "j"))),
-            writes=(Access("o", ("j",)), Access("l", ("h",))),
+            writes=(Access("o", ("h", "c")), Access("l", ("h",))),
             body=body, out_dtype=(jnp.float32, jnp.float32),
-            reduce=OnlineSoftmax(groups=1, vwidth=cols, with_lse=True),
+            reduce=OnlineSoftmax(groups=g, vwidth=vw, with_lse=True),
             full_width=True,
         )
         return Case(spec, (x, v), tuple(_divisors(rows)),
@@ -378,10 +383,11 @@ def draw_case(draw: Draw) -> Case:
 
         def body(env):
             sc = env["x"].astype(jnp.float32).sum(axis=-1)
-            m = sc.max()[None]
-            w = jnp.exp(sc - m)
-            num = (w[:, None] * env["v"].astype(jnp.float32)).sum(axis=0)
-            return (m, num, w.sum()[None])
+            m = sc.max(keepdims=True)[None]                # (1, 1)
+            w = jnp.exp(sc - m[0])
+            num = (w[:, None] * env["v"].astype(jnp.float32)).sum(
+                axis=0, keepdims=True)                     # (1, cols)
+            return (m, num, w.sum(keepdims=True)[None])
 
         spec = TraversalSpec(
             name="prop_osm",

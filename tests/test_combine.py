@@ -6,7 +6,8 @@ combinator must be a monoid: associative merge, two-sided identity from
 ``init``.  ``OnlineSoftmax`` additionally exercises the rescaling path
 — merging states whose maxima arrive in either order must agree (the
 disjoint-max ordering case) and must equal the direct full-softmax
-computation.  The padded-rows refusal is checked for EVERY combinator:
+computation — in its ``[groups, …]`` state layout, with and without
+leading batch dims.  The padded-rows refusal is checked for EVERY combinator:
 zero-padded stride rows cannot be trusted to contribute the combine
 identity through an arbitrary body, so the emitter must raise rather
 than silently corrupt.
@@ -30,11 +31,13 @@ def _osm():
     return OnlineSoftmax(groups=2, vwidth=4)
 
 
-def _osm_state(key, m_scale=1.0, m_shift=0.0):
+def _osm_state(key, m_scale=1.0, m_shift=0.0, lead=()):
+    """A (m, num, den) state of ``_osm()``: [groups, 1] / [groups,
+    vwidth] / [groups, 1], after any ``lead`` batch dims."""
     k1, k2, k3 = jax.random.split(key, 3)
-    m = jax.random.normal(k1, (2,), jnp.float32) * m_scale + m_shift
-    num = jax.random.normal(k2, (8,), jnp.float32)
-    den = jnp.abs(jax.random.normal(k3, (2,), jnp.float32)) + 0.1
+    m = jax.random.normal(k1, lead + (2, 1), jnp.float32) * m_scale + m_shift
+    num = jax.random.normal(k2, lead + (2, 4), jnp.float32)
+    den = jnp.abs(jax.random.normal(k3, lead + (2, 1), jnp.float32)) + 0.1
     return (m, num, den)
 
 
@@ -89,8 +92,9 @@ def test_online_softmax_rescaling_disjoint_max_ordering():
     the direct two-block softmax: the rescale factors exp(mᵢ - m) hit
     1 and underflow-to-0 in opposite orders."""
     comb = _osm()
-    lo = (jnp.full((2,), -50.0), jnp.ones((8,)), jnp.full((2,), 0.5))
-    hi = (jnp.full((2,), +40.0), 2.0 * jnp.ones((8,)), jnp.full((2,), 2.0))
+    lo = (jnp.full((2, 1), -50.0), jnp.ones((2, 4)), jnp.full((2, 1), 0.5))
+    hi = (jnp.full((2, 1), +40.0), 2.0 * jnp.ones((2, 4)),
+          jnp.full((2, 1), 2.0))
     ab = comb.merge(lo, hi)
     ba = comb.merge(hi, lo)
     _assert_state_close(ab, ba, rtol=1e-6, atol=0)
@@ -106,11 +110,10 @@ def test_online_softmax_rescaling_disjoint_max_ordering():
 
     def lift(s):
         a = np.exp(np.asarray(s[0]) - m)
-        return (np.asarray(s[1]).reshape(2, 4) * a[:, None],
-                np.asarray(s[2]) * a)
+        return np.asarray(s[1]) * a, np.asarray(s[2]) * a
     n1, d1 = lift(s1)
     n2, d2 = lift(s2)
-    want = ((n1 + n2) / (d1 + d2)[:, None]).reshape(8)
+    want = (n1 + n2) / (d1 + d2)
     np.testing.assert_allclose(np.asarray(merged), want, rtol=1e-5,
                                atol=1e-6)
 
@@ -118,8 +121,28 @@ def test_online_softmax_rescaling_disjoint_max_ordering():
 def test_online_softmax_state_widths_validate():
     comb = _osm()
     assert comb.state_widths(8) == (2, 8, 2)
+    assert comb.state_shapes(8) == ((1, 2, 1), (1, 2, 4), (1, 2, 1))
     with pytest.raises(ValueError):
         comb.state_widths(9)
+    with pytest.raises(ValueError):
+        comb.state_shapes(9)
+
+
+def test_online_softmax_merges_batched_states_row_by_row():
+    """A decode step's state carries leading batch dims ([b, Hq, …]):
+    merging and finalizing the batched state equals doing it per row."""
+    comb = OnlineSoftmax(groups=2, vwidth=4, with_lse=True)
+    s1 = _osm_state(jax.random.PRNGKey(3), m_shift=+2.0, lead=(3,))
+    s2 = _osm_state(jax.random.PRNGKey(4), m_shift=-2.0, lead=(3,))
+    out, lse = comb.finalize(comb.merge(s1, s2))
+    assert out.shape == (3, 2, 4) and lse.shape == (3, 2, 1)
+    for b in range(3):
+        row = comb.finalize(comb.merge(tuple(x[b] for x in s1),
+                                       tuple(x[b] for x in s2)))
+        np.testing.assert_allclose(np.asarray(out[b]), np.asarray(row[0]),
+                                   rtol=1e-6)
+        np.testing.assert_allclose(np.asarray(lse[b]), np.asarray(row[1]),
+                                   rtol=1e-6)
 
 
 def test_resolve_combine():
@@ -141,11 +164,12 @@ def test_resolve_combine():
 def _stride_red_spec(rows, cols, reduce):
     def body(env):
         x = env["x"].astype(jnp.float32)
-        if isinstance(reduce, OnlineSoftmax):
+        if isinstance(reduce, OnlineSoftmax):       # one group: [1, …]
             sc = x.sum(axis=-1)
-            m = sc.max()[None]
+            m = sc.max(keepdims=True)
             w = jnp.exp(sc - m)
-            return (m, (w[:, None] * x).sum(axis=0), w.sum()[None])
+            return (m[None], (w[:, None] * x).sum(axis=0, keepdims=True),
+                    w.sum(keepdims=True)[None])
         if reduce == "max":
             return x.max(axis=0)
         return x.sum(axis=0)
@@ -194,12 +218,12 @@ def test_online_softmax_with_lse_finalize():
     assert lse_c.finalizing and base.finalizing
 
     def part(scores, values):
-        m = scores.max(axis=-1)
-        w = np.exp(scores - m[..., None])
-        num = np.einsum("gs,gsv->gv", w, values).reshape(-1)
+        m = scores.max(axis=-1, keepdims=True)
+        w = np.exp(scores - m)
+        num = np.einsum("gs,gsv->gv", w, values)
         return (jnp.asarray(m, jnp.float32),
                 jnp.asarray(num, jnp.float32),
-                jnp.asarray(w.sum(axis=-1), jnp.float32))
+                jnp.asarray(w.sum(axis=-1, keepdims=True), jnp.float32))
 
     scores = rng.normal(size=(2, groups, 8))
     values = rng.normal(size=(2, groups, 8, vwidth))
@@ -210,5 +234,5 @@ def test_online_softmax_with_lse_finalize():
         np.asarray(out), np.asarray(base.finalize(merged)), rtol=1e-6)
     all_scores = np.concatenate([scores[0], scores[1]], axis=-1)
     m = all_scores.max(axis=-1, keepdims=True)
-    want_lse = (m[:, 0] + np.log(np.exp(all_scores - m).sum(axis=-1)))
+    want_lse = m + np.log(np.exp(all_scores - m).sum(axis=-1, keepdims=True))
     np.testing.assert_allclose(np.asarray(lse), want_lse, rtol=1e-5)

@@ -13,18 +13,16 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 
 # ---------------- compressed pmean ----------------
-from repro import compat
 from repro.train.compression import compressed_pmean, ef_compressed_pmean, ef_init
 mesh = jax.make_mesh((2, 4), ("pod", "data"))
 g = jax.random.normal(jax.random.PRNGKey(0), (2, 257))  # pod-varying grads
 
-# NOTE: full-manual shard_map (no axis_names) — jax 0.4.37's XLA crashes
-# on all_to_all/all_gather inside manual-*subgroup* (partial-manual)
-# regions; the compression math only needs the pod axis collectives.
+# full-manual shard_map (no axis_names): the compression math only
+# needs the pod axis collectives
 def sync(x):
-    return compat.shard_map(lambda v: compressed_pmean(v, "pod"), mesh=mesh,
-                            in_specs=P("pod"), out_specs=P("pod"),
-                            check_vma=False)(x)
+    return jax.shard_map(lambda v: compressed_pmean(v, "pod"), mesh=mesh,
+                         in_specs=P("pod"), out_specs=P("pod"),
+                         check_vma=False)(x)
 
 out = jax.jit(sync)(g)
 true = jnp.broadcast_to(g.mean(axis=0, keepdims=True), g.shape)
@@ -40,7 +38,7 @@ def body(v, e):
     sg, new_e = ef_compressed_pmean({"g": v}, {"g": e}, "pod")
     return sg["g"], new_e["g"]
 
-ef_step = jax.jit(compat.shard_map(
+ef_step = jax.jit(jax.shard_map(
     body, mesh=mesh, in_specs=(P("pod"), P("pod")),
     out_specs=(P("pod"), P("pod")), check_vma=False))
 total = jnp.zeros((2, 257))

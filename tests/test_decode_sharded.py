@@ -185,8 +185,11 @@ def _teacher_force_ragged(model, params, tokens, lens, shards=1):
         adv = [r for r in range(n) if t < lens[r]]
         for r in adv:
             toks[r, 0] = int(tokens[r, t])
+        # jnp.array copies: on the CPU jnp.asarray may alias the numpy
+        # buffer, and the in-place length bump below would race the
+        # still-running asynchronous step
         logits, cache = model.decode_step(params, jnp.asarray(toks), cache,
-                                          jnp.asarray(lengths, jnp.int32),
+                                          jnp.array(lengths, jnp.int32),
                                           **kw)
         for r in adv:
             lengths[r] += 1

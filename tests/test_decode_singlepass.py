@@ -48,8 +48,8 @@ def _inputs(key=0, scale=1.0):
 
 def test_single_pass_plan_reads_k_once():
     kc2 = jax.ShapeDtypeStruct((B, S, HKV * DH), jnp.float32)
-    q2 = jax.ShapeDtypeStruct((B, HQ * DH), jnp.float32)
-    spec = _decode_spec(HKV, DH)(kc2, kc2, q2)
+    q3 = jax.ShapeDtypeStruct((B, HQ, DH), jnp.float32)
+    spec = _decode_spec(HKV, DH)(kc2, kc2, q3)
     info = classify(spec)
     assert info.stride_reduction            # ONE stream-reduction pass
     assert info.stride_axis == "s" and info.batch_axes == ("b",)
@@ -68,7 +68,7 @@ def test_single_pass_single_spec_module():
     spec = fw._decode_spec(2, 8)(
         jax.ShapeDtypeStruct((1, 32, 16), jnp.float32),
         jax.ShapeDtypeStruct((1, 32, 16), jnp.float32),
-        jax.ShapeDtypeStruct((1, 32), jnp.float32))
+        jax.ShapeDtypeStruct((1, 4, 8), jnp.float32))
     assert spec.combine.name == "online_softmax"
     # ONE accumulated state, TWO native outputs with distinct access
     # maps: the attention row plus the Hq-wide log-sum-exp finalized
@@ -161,3 +161,49 @@ def test_matches_registry_reference(mode):
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(want, np.float32),
                                    rtol=2e-5, atol=2e-5)
+
+
+# ------------------------------------------------- tiles on the chip
+
+@pytest.mark.parametrize("mode", ["ref", "interpret"])
+def test_padded_sequence_matches_reference(mode):
+    """A sequence no D splits into whole lane tiles is padded on the chip
+    and its tail masked: the padded sweep equals attention over the
+    unpadded cache, ragged lengths included."""
+    from repro.kernels.decode_attn import ops
+    from repro.kernels.decode_attn.ref import decode_attn_ref
+    s = 200                                   # pads to 256
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(ks[0], (2, HQ, DH), jnp.float32)
+    k = jax.random.normal(ks[1], (2, s, HKV, DH), jnp.float32)
+    v = jax.random.normal(ks[2], (2, s, HKV, DH), jnp.float32)
+    kv_len = jnp.asarray([s, 77])
+    out, _ = ops._decode_attn_masked(q, k, v, kv_len, StridingConfig(2, 1),
+                                     mode, seq_pad=56)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(decode_attn_ref(q, k, v, kv_len)),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_pallas_resolution_keeps_streams_whole_tiles():
+    """In pallas mode each stream holds whole tiles of its spec: 128 rows
+    where the validity rows ride the lanes, 8 otherwise, the largest of a
+    composite's specs; the interpreter and the oracle take any D."""
+    from repro.kernels import common
+    from repro.kernels.decode_attn import ops
+    q = jax.ShapeDtypeStruct((8, HQ, DH), jnp.bfloat16)
+    kc = jax.ShapeDtypeStruct((8, 256, HKV, DH), jnp.bfloat16)
+    masked, full = ops._spec(q, kc, True, 256), ops._spec(q, kc, False, 256)
+    assert common.row_align(masked, "pallas") == 128
+    assert common.row_align(full, "pallas") == 8
+    assert common.row_align((full, masked), "pallas") == 128
+    assert common.row_align(None, "pallas") == 8
+    assert common.row_align(masked, "interpret") == 1
+
+    def d_for(spec, rows, mode):
+        return common.resolve_config(
+            "tile_probe", kc.shape, kc.dtype, StridingConfig(4, 1), rows,
+            StridingConfig(4, 1), mode=mode, spec=spec).stride_unroll
+    assert d_for(masked, 256, "pallas") == 2      # 128 rows per stream
+    assert d_for(full, 256, "pallas") == 4        # 64 rows per stream
+    assert d_for(masked, 256, "ref") == 4

@@ -202,6 +202,27 @@ def test_all_tiers_exhausted_reraises_original(isolated_cache):
     assert "ref" in calls     # the chain did reach the last tier
 
 
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_interpret_tier_never_taken_on_tpu(isolated_cache, monkeypatch,
+                                           backend):
+    """A failed pallas kernel degrades through interpret only off the
+    chip: on a TPU the interpreter is a silent slowdown of orders of
+    magnitude, so the chain goes straight to the ref oracle."""
+    monkeypatch.setattr(common.jax, "default_backend", lambda: backend)
+    calls = []
+
+    def run(cfg, mode):
+        calls.append(mode)
+        if mode != "ref":
+            raise NotImplementedError("refused")
+        return jnp.zeros(())
+
+    common.guarded_run("fake_kernel", run, SINGLE_STRIDED, "pallas",
+                       shape=(4, 4), dtype=jnp.float32)
+    assert calls[-1] == "ref"
+    assert ("interpret" in calls) == (backend != "tpu")
+
+
 # ------------------------------------------------- self-healing caches
 
 def test_corrupt_cache_quarantined_and_rebuilt(tmp_path):

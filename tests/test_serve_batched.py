@@ -172,3 +172,27 @@ def test_prefill_deadline_expires_mid_prompt_and_slot_reusable():
     results = eng.run()
     assert len(results[2]) == 2
     assert eng.stats()["requests"][2]["deadline_exceeded"] is False
+
+
+def test_serve_launcher_widths_and_shared_construction():
+    """The launcher keeps published widths unless ``--reduced`` (the
+    depth cut alone leaves them), and its ``build_engine`` serves every
+    request — the construction ``chip_smoke.py`` reuses."""
+    from repro.launch import serve
+
+    args = serve.parser().parse_args(["--layers", "8"])
+    full = serve.model_config(args)
+    assert (full.d_model, full.n_heads, full.n_kv_heads, full.d_ff,
+            full.vocab_size, full.n_layers) == (4096, 32, 4, 11008, 64000, 8)
+
+    args = serve.parser().parse_args(
+        ["--reduced", "--layers", "1", "--requests", "3", "--slots", "2",
+         "--prompt-len", "4", "--max-new", "3", "--max-len", "16"])
+    cfg, _model, _params, engine = serve.build_engine(args)
+    assert cfg.n_layers == 1 and cfg.d_model < full.d_model
+    assert engine.cfg.max_len == 16
+    for uid, toks in enumerate(serve.prompts(args, cfg.vocab_size)):
+        engine.submit(uid, toks)
+    results = engine.run()
+    assert sorted(results) == [0, 1, 2]
+    assert all(len(r) == 3 for r in results.values())
