@@ -67,19 +67,13 @@ def main(argv=None) -> None:
         "roofline": roofline_table.run,
         "serving_sweep": serving_sweep.run,
     }
-    from repro import obs
     only = set(args.only.split(",")) if args.only else None
     results: dict[str, list[dict]] = {}
     for name, fn in tables.items():
         if only and name not in only:
             continue
         print(f"# --- {name} ---", file=sys.stderr)
-        # with $REPRO_OBS set, each table is one timed span (row count
-        # attached) — the coarse layer of the telemetry trace
-        with obs.span("bench.table", table=name) as sp:
-            rows = fn(quick=args.quick) or []
-            sp.set(rows=len(rows))
-        results[name] = rows
+        results[name] = fn(quick=args.quick) or []
     if args.json:
         with open(args.json, "w") as f:
             json.dump(_json_payload(results, args.quick), f, indent=1,

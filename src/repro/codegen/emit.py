@@ -508,6 +508,7 @@ def _emit_streaming(sched, bp, arrays, scalars, interpret: bool):
         out_shape=[jax.ShapeDtypeStruct(out_buf_shape(wp), jnp.dtype(dt))
                    for wp, dt in zip(wplans, out_dtypes)],
         interpret=interpret,
+        name=spec.name,
     )(*operands)
     res = tuple(
         o.reshape(*wp.batch_ext, *wp.shape_tail, d * seg_rows)
@@ -588,6 +589,7 @@ def _emit_reduction(sched, bp, arrays, scalars, interpret: bool):
         scratch_shapes=[pltpu.VMEM((d, bp.bm, 1), jnp.float32)
                         for _ in range(n_out)],
         interpret=interpret,
+        name=spec.name,
     )(*operands)
     res = tuple(o.reshape(d * seg_rows) for o in out)
     return res[0] if n_out == 1 else res
@@ -727,6 +729,7 @@ def _emit_stream_reduction(sched, bp, arrays, scalars, interpret: bool):
                    for shape, dt in zip(out_shapes, out_dtypes)],
         scratch_shapes=[pltpu.VMEM(s, jnp.float32) for s in state_shapes],
         interpret=interpret,
+        name=spec.name,
     )(*operands)
     res = tuple(o.reshape(f) for o, f in zip(out, finals))
     return res[0] if n_out == 1 else res
@@ -868,6 +871,7 @@ def _emit_manual(sched, bp, arrays, scalars, interpret: bool):
             + [pltpu.SemaphoreType.DMA((ost, d)) for _ in range(n_out)]
         ),
         interpret=interpret,
+        name=spec.name,
     )(*arrays, *scal_arrays)
     res = tuple(o.reshape(-1) if len(w.index) == 1 else o
                 for o, w in zip(out, spec.writes))

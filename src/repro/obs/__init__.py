@@ -20,9 +20,16 @@ Instrumented layers and their event names (see README § Observability):
   tune.cache.*             autotune-level cache hit/miss counters
   tunecache.*              entry-level hit/miss/sibling_fallback counters
   serve.step               per-token decode/prefill step: latency,
-                           active slots, queue depth
-  serve.request            per-request TTFT / tokens-per-second
-  bench.table              one span per benchmarks.run table
+                           active slots, queue depth, request uids
+  serve.request            per-request TTFT (queue_s +
+                           first_token_wait_s) / tokens-per-second
+  serve.run, serve.admit,  serving spans: one run(), a request into a
+  serve.prefill,           slot (uid, slot, queue_s), its prompt (uid,
+  serve.retire             tokens), its retirement (uid, n_tokens)
+  serve.round,             per decode round (uids) and per step (phase):
+  serve.dispatch,          no Event; their times add up in
+  serve.sync,              engine.stats()["host"]
+  serve.bookkeep
   analysis.pass            static verifier validated a (spec, config)
   analysis.violation       one event per static finding: rule id,
                            severity, locus, message

@@ -19,10 +19,11 @@ Serving telemetry (always collected engine-side; exported via
 events):
 
   * ``serve.step``    — one event per fused decode/prefill step:
-    wall-clock latency, phase, the advanced slots + their positions,
-    active-slot count, queue depth;
+    wall-clock latency, phase, the advanced slots + their positions and
+    request uids, active-slot count, queue depth;
   * ``serve.request`` — one event per retired request: time-to-first-
-    token, tokens/s, generated-token count;
+    token split into its queue wait and the wait from admission,
+    tokens/s, generated-token count;
   * ``serve.shed``    — a request refused (or evicted) by the bounded
     admission queue;
   * ``serve.deadline``— a request retired because its per-request
@@ -30,6 +31,17 @@ events):
   * ``serve.slow_step`` — a slot's step slower than
     ``slow_step_factor`` × the slot's rolling median (StepMonitor
     straggler machinery).
+
+Spans (``repro.obs.span``: profiler annotations while a trace records;
+``uid`` names the request): ``serve.run`` one ``run()``; ``serve.admit``
+one request from the queue into a slot, its prefill included;
+``serve.prefill`` its teacher-forced prompt; ``serve.round`` one decode
+round; per step ``serve.dispatch`` (stall checks, inputs to the device,
+the jitted call), ``serve.sync`` (the argmax and its copy to the host:
+the wait on the device) and ``serve.bookkeep`` (lengths, monitor,
+heartbeat, the ``serve.step`` emission); ``serve.retire``.  The
+per-step and per-round spans emit no Event; their times add up in
+``stats()["host"]`` whether or not ``repro.obs`` is enabled.
 """
 from __future__ import annotations
 
@@ -70,6 +82,7 @@ class Request:
     out: list = dataclasses.field(default_factory=list)
     done: bool = False
     submitted_at: float = 0.0    # perf_counter at submit()
+    admitted_at: float = 0.0     # perf_counter when it took a slot
     first_token_at: float = 0.0  # perf_counter at first generated token
 
 
@@ -118,6 +131,13 @@ class ServingEngine:
         self._last_step_s = 0.0
         self._tokens_generated = 0
         self._requests: dict[int, dict[str, float]] = {}
+        # host time by span, totals over the engine's life (stats()["host"])
+        self._host = {"steps": 0, "dispatch_s": 0.0, "sync_s": 0.0,
+                      "bookkeep_s": 0.0, "between_s": 0.0, "betweens": 0,
+                      "queue_s": 0.0, "admitted": 0,
+                      "first_token_wait_s": 0.0, "first_tokens": 0}
+        self._self_s: dict[str, float] = {}    # span name -> self seconds
+        self._synced_at: Optional[float] = None   # last step's sync, in run()
         # robustness state: bounded-queue shedding, per-request deadlines,
         # slow-step/straggler detection over per-slot step times
         self._shed = 0
@@ -205,9 +225,16 @@ class ServingEngine:
                     self._expired_uids.append(req.uid)
                     self._expire(req, where="queue")
                     continue         # expired: try the next queued request
-                self.slots[i] = req
-                self.lengths[i] = 0
-                self._prefill(i, req)   # on lapse the slot is free again
+                with obs.span("serve.admit", tally=self._self_s,
+                              uid=req.uid, slot=i) as sp:
+                    req.admitted_at = sp.start
+                    queue_s = sp.start - req.submitted_at
+                    sp.set(queue_s=queue_s)
+                    self._host["queue_s"] += queue_s
+                    self._host["admitted"] += 1
+                    self.slots[i] = req
+                    self.lengths[i] = 0
+                    self._prefill(i, req)  # on lapse the slot is free again
 
     def _prefill(self, i: int, req: Request) -> bool:
         """Teacher-force the prompt into slot ``i`` one token per fused
@@ -216,16 +243,19 @@ class ServingEngine:
         Returns False (slot freed, partial cache rows reusable — the
         next occupant restarts at length 0 and overwrites them) when the
         deadline lapses mid-prompt."""
-        for t_idx, tok in enumerate(req.tokens[:-1]):  # last token: decode
-            if t_idx and self._expired(req):
-                self.slots[i] = None
-                self.lengths[i] = 0
-                self._expired_uids.append(req.uid)
-                self._expire(req, where="prefill")
-                return False
-            toks = np.zeros((self.cfg.slots, 1), np.int32)
-            toks[i, 0] = int(tok)
-            self._step(toks, [i], phase="prefill")
+        prompt = req.tokens[:-1]                     # last token: decode
+        with obs.span("serve.prefill", tally=self._self_s, uid=req.uid,
+                      tokens=len(prompt)):
+            for t_idx, tok in enumerate(prompt):
+                if t_idx and self._expired(req):
+                    self.slots[i] = None
+                    self.lengths[i] = 0
+                    self._expired_uids.append(req.uid)
+                    self._expire(req, where="prefill")
+                    return False
+                toks = np.zeros((self.cfg.slots, 1), np.int32)
+                toks[i, 0] = int(tok)
+                self._step(toks, [i], phase="prefill")
         return True
 
     def _step(self, toks: np.ndarray, advance: list[int],
@@ -241,41 +271,57 @@ class ServingEngine:
         time plus its own injected stall.
         """
         from repro.runtime import faults
-        t0 = time.perf_counter()
-        stalls = []
-        for i in advance:
-            s0 = time.perf_counter()
-            faults.sleep_if("serve_slow", f"slot{i}")   # injected stall
-            stalls.append(time.perf_counter() - s0)
-        # a copy: on the CPU jnp.asarray may alias the numpy buffer that
-        # the length bump below mutates while the step can still run
-        pos = jnp.array(self.lengths, jnp.int32)
-        logits, self.cache = self._decode(self.params, jnp.asarray(toks),
-                                          self.cache, pos)
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))  # sync = step edge
-        latency = time.perf_counter() - t0
-        base = max(latency - sum(stalls), 0.0)
-        for i in advance:
-            self.lengths[i] += 1
-        self._steps[phase] += 1
-        self._step_s[phase] += latency
-        self._last_step_s = latency
-        self.heartbeats.beat("engine")
-        for i, stall in zip(advance, stalls):
-            host = f"slot{i}"
-            slot_lat = base + stall
-            med = self.monitor.medians().get(host, 0.0)
-            self.monitor.record(host, slot_lat)
-            if med > 0 and slot_lat > self.cfg.slow_step_factor * med:
-                self._slow_steps += 1
-                if obs.enabled():
-                    obs.event("serve.slow_step", slot=i, phase=phase,
-                              latency_s=slot_lat, median_s=med)
-        if obs.enabled():
-            obs.event("serve.step", phase=phase, slots=list(advance),
-                      latency_s=latency, active_slots=self.active_slots(),
-                      queue_depth=len(self.queue),
-                      pos=[int(self.lengths[i]) - 1 for i in advance])
+        host, tally = self._host, self._self_s
+        with obs.span("serve.dispatch", emit=False, tally=tally,
+                      phase=phase) as dispatch:
+            stalls = []
+            for i in advance:
+                s0 = time.perf_counter()
+                faults.sleep_if("serve_slow", f"slot{i}")  # injected stall
+                stalls.append(time.perf_counter() - s0)
+            # a copy: on the CPU jnp.asarray may alias the numpy buffer
+            # that the length bump below mutates while the step can run
+            pos = jnp.array(self.lengths, jnp.int32)
+            logits, self.cache = self._decode(
+                self.params, jnp.asarray(toks), self.cache, pos)
+        with obs.span("serve.sync", emit=False, tally=tally,
+                      phase=phase) as sync:
+            nxt = np.asarray(jnp.argmax(logits, axis=-1))  # the step edge
+        with obs.span("serve.bookkeep", emit=False, tally=tally,
+                      phase=phase) as bookkeep:
+            if self._synced_at is not None:
+                host["between_s"] += dispatch.start - self._synced_at
+                host["betweens"] += 1
+            self._synced_at = sync.end
+            host["steps"] += 1
+            host["dispatch_s"] += dispatch.duration_s
+            host["sync_s"] += sync.duration_s
+            latency = sync.end - dispatch.start
+            base = max(latency - sum(stalls), 0.0)
+            for i in advance:
+                self.lengths[i] += 1
+            self._steps[phase] += 1
+            self._step_s[phase] += latency
+            self._last_step_s = latency
+            self.heartbeats.beat("engine")
+            for i, stall in zip(advance, stalls):
+                slot = f"slot{i}"
+                slot_lat = base + stall
+                med = self.monitor.medians().get(slot, 0.0)
+                self.monitor.record(slot, slot_lat)
+                if med > 0 and slot_lat > self.cfg.slow_step_factor * med:
+                    self._slow_steps += 1
+                    if obs.enabled():
+                        obs.event("serve.slow_step", slot=i, phase=phase,
+                                  latency_s=slot_lat, median_s=med)
+            if obs.enabled():
+                obs.event("serve.step", phase=phase, slots=list(advance),
+                          uids=[self.slots[i].uid for i in advance],
+                          latency_s=latency,
+                          active_slots=self.active_slots(),
+                          queue_depth=len(self.queue),
+                          pos=[int(self.lengths[i]) - 1 for i in advance])
+        host["bookkeep_s"] += bookkeep.duration_s
         return nxt
 
     # ------------------------------------------------------------ stats
@@ -285,18 +331,23 @@ class ServingEngine:
     def _retire(self, req: Request, deadline_exceeded: bool = False,
                 ) -> None:
         """Record per-request serving metrics as the slot frees."""
-        now = time.perf_counter()
-        ttft = (req.first_token_at - req.submitted_at
-                if req.first_token_at else 0.0)
-        gen_s = now - (req.first_token_at or req.submitted_at)
         n = len(req.out)
-        rec = {"n_tokens": n, "ttft_s": ttft,
-               "tokens_per_s": (n / gen_s if gen_s > 0 else 0.0),
-               "deadline_exceeded": deadline_exceeded, "shed": False}
-        self._requests[req.uid] = rec
-        self._tokens_generated += n
-        if obs.enabled():
-            obs.event("serve.request", uid=req.uid, **rec)
+        with obs.span("serve.retire", tally=self._self_s, uid=req.uid,
+                      n_tokens=n) as sp:
+            first = req.first_token_at
+            gen_s = sp.start - (first or req.submitted_at)
+            rec = {"n_tokens": n,
+                   "ttft_s": first - req.submitted_at if first else 0.0,
+                   "tokens_per_s": (n / gen_s if gen_s > 0 else 0.0),
+                   "deadline_exceeded": deadline_exceeded, "shed": False}
+            self._requests[req.uid] = rec
+            self._tokens_generated += n
+            if obs.enabled():
+                obs.event("serve.request", uid=req.uid, **rec,
+                          queue_s=(req.admitted_at - req.submitted_at
+                                   if req.admitted_at else 0.0),
+                          first_token_wait_s=(first - req.admitted_at
+                                              if first else 0.0))
 
     def stats(self) -> dict[str, Any]:
         """Serving-telemetry snapshot (plain dict, json-clean).
@@ -310,6 +361,14 @@ class ServingEngine:
         ``slow_steps``, the StepMonitor's ``straggler_slots``, and
         ``heartbeat_alive`` (engine-loop liveness within
         ``heartbeat_timeout_s``).
+
+        ``host`` holds running totals of the engine's host time, read
+        from its spans: ``steps`` and their ``dispatch_s``, ``sync_s``
+        and ``bookkeep_s``; ``between_s`` over ``betweens``, from one
+        step's synced result to the next step's start within one
+        ``run()``; ``queue_s`` over ``admitted`` (submit → slot);
+        ``first_token_wait_s`` over ``first_tokens`` (slot → first
+        generated token); and ``self_s``, each span's self seconds.
         """
         dec, pre = self._steps["decode"], self._steps["prefill"]
         return {
@@ -331,6 +390,7 @@ class ServingEngine:
             "tokens_generated": self._tokens_generated,
             "requests": {uid: dict(rec)
                          for uid, rec in self._requests.items()},
+            "host": {**self._host, "self_s": dict(self._self_s)},
         }
 
     # ------------------------------------------------------------- run
@@ -341,38 +401,50 @@ class ServingEngine:
         many slots are active: the per-slot token/position vectors make
         the batch ragged-correct, so a round costs one compiled dispatch
         instead of one per active slot."""
-        cfg = self.cfg
+        with obs.span("serve.run", tally=self._self_s):
+            self._synced_at = None   # the host gap counts within one run
+            return self._run(max_steps)
+
+    def _run(self, max_steps: int) -> dict[int, list[int]]:
+        cfg, host = self.cfg, self._host
         results: dict[int, list[int]] = {}
         steps = 0
         self._admit()
         while any(s is not None for s in self.slots) and steps < max_steps:
-            for i, req in enumerate(self.slots):
-                if req is not None and self._expired(req):
-                    # deadline lapsed mid-generation: return the partial
-                    # output rather than burning more steps on it
-                    results[req.uid] = req.out
-                    self.slots[i] = None
-                    self._expire(req, where="slot")
-            active = [i for i, r in enumerate(self.slots) if r is not None]
-            if active:
-                toks = np.zeros((cfg.slots, 1), np.int32)
-                for i in active:
-                    req = self.slots[i]
-                    toks[i, 0] = (req.out[-1] if req.out
-                                  else int(req.tokens[-1]))
-                nxt = self._step(toks, active, phase="decode")
-                now = time.perf_counter()
-                for i in active:
-                    req = self.slots[i]
-                    req.out.append(int(nxt[i]))
-                    if not req.first_token_at:
-                        req.first_token_at = now
-                    if (req.out[-1] == cfg.eos_id
-                            or len(req.out) >= cfg.max_new_tokens
-                            or self.lengths[i] >= cfg.max_len - 1):
+            with obs.span("serve.round", emit=False,
+                          tally=self._self_s) as rnd:
+                for i, req in enumerate(self.slots):
+                    if req is not None and self._expired(req):
+                        # deadline lapsed mid-generation: return the
+                        # partial output rather than burning more steps
                         results[req.uid] = req.out
                         self.slots[i] = None
-                        self._retire(req)
+                        self._expire(req, where="slot")
+                active = [i for i, r in enumerate(self.slots)
+                          if r is not None]
+                if active:
+                    rnd.set(uids=[self.slots[i].uid for i in active])
+                    toks = np.zeros((cfg.slots, 1), np.int32)
+                    for i in active:
+                        req = self.slots[i]
+                        toks[i, 0] = (req.out[-1] if req.out
+                                      else int(req.tokens[-1]))
+                    nxt = self._step(toks, active, phase="decode")
+                    now = self._synced_at
+                    for i in active:
+                        req = self.slots[i]
+                        req.out.append(int(nxt[i]))
+                        if not req.first_token_at:
+                            req.first_token_at = now
+                            host["first_token_wait_s"] += (
+                                now - req.admitted_at)
+                            host["first_tokens"] += 1
+                        if (req.out[-1] == cfg.eos_id
+                                or len(req.out) >= cfg.max_new_tokens
+                                or self.lengths[i] >= cfg.max_len - 1):
+                            results[req.uid] = req.out
+                            self.slots[i] = None
+                            self._retire(req)
             self._admit()
             steps += 1
         for i, req in enumerate(self.slots):

@@ -89,6 +89,34 @@ def test_decode_attn_compiles_for_v5e(one_chip, masked, seq):
     assert "tpu_custom_call" in text
 
 
+def test_kernels_keep_their_names_without_wrappers(one_chip):
+    """A device trace finds each kernel by its custom call's name.  The
+    kernel names itself (``pallas_call(name=...)``, the spec's name), so
+    the name survives calling it without the jitted wrapper that used to
+    lend it one."""
+    import re
+
+    from repro.core.striding import StridingConfig
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    cfg = StridingConfig(1, 1)
+    norm = rmsnorm_ops._rmsnorm.__wrapped__
+    attn = decode_ops._decode_attn_masked.__wrapped__
+    text = _compiled_text(
+        lambda x, w, q, k, n: (norm(x, w, 1e-5, cfg, "pallas"),
+                               attn(q, k, k, n, cfg, "pallas")),
+        sds((SLOTS, 1, D_MODEL), jnp.bfloat16), sds((D_MODEL,), jnp.bfloat16),
+        sds((SLOTS, HQ, DH), jnp.bfloat16),
+        sds((SLOTS, MAX_LEN, HKV, DH), jnp.bfloat16),
+        sds((SLOTS,), jnp.int32))
+    names = sorted(re.findall(r"^\s*(?:ROOT )?%(\S+) = .*tpu_custom_call",
+                              text, re.M))
+    assert len(names) == 2
+    assert names[0].startswith("decode_attn") and \
+        names[1].startswith("rmsnorm")
+
+
 def test_sharded_decode_step_compiles_for_v5e_2x2(topo, one_chip,
                                                   monkeypatch):
     """The whole KV-sharded serving step (``shards=4``) over the four
