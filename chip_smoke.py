@@ -39,11 +39,12 @@ ROOT = os.path.dirname(os.path.abspath(__file__))
 
 # Depth cut, sized from ``memory_analysis()`` of the decode step compiled
 # for one v5e (16 GiB HBM; ``repro.launch.serve.decode_step_memory``,
-# kept true by tests/test_chip_compile.py): at 8 layers it needs 7.36 GiB
-# of arguments (f32 weights and the bf16 cache), 0.25 GiB of outputs and
-# 2.55 GiB of temporaries, 10.2 GiB in all; at 12 layers 14.3 GiB, too
-# close to the 16 GiB once the logits check keeps its own cache and
-# program.
+# kept true by tests/test_chip_compile.py): at 8 layers it needs 3.81 GiB
+# of arguments (the engine's bf16 weights and the bf16 cache), 0.25 GiB
+# of outputs and under 1 MiB of temporaries, beside the 7.11 GiB of f32
+# weights this script keeps for the full-forward check, 11.17 GiB in
+# all; at 12 layers 15.28 GiB, too close to the 16 GiB once the logits
+# check keeps its own cache and program.
 LAYERS = 8
 
 # Tolerance of the logits check.  Both sides run bf16 activations

@@ -92,15 +92,19 @@ def decode_step_memory(args, sharding) -> dict[str, int]:
     """Bytes one compiled decode step of ``args``'s configuration holds
     on a device (``memory_analysis()``), compiled ahead of time for
     ``sharding``'s device — a chip, or one of a described topology, so no
-    chip is needed.  Weights, KV cache, outputs and temporaries must fit
-    the chip's memory: this sizes the depth cut."""
+    chip is needed — with the engine's weights (``serving_params``, in
+    the compute dtype).  ``held_params`` is the tree the caller made and
+    keeps beside them (``build_engine`` returns it).  Weights, KV cache,
+    outputs and temporaries must fit the chip's memory: ``total`` sizes
+    the depth cut."""
     from repro.serve.engine import _decode_fn
     model = build_model(model_config(args))
 
     def placed(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
             a.shape, a.dtype, sharding=sharding), tree)
-    params = placed(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    held = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    params = placed(jax.eval_shape(model.serving_params, held))
     cache = placed(jax.eval_shape(
         lambda: model.init_cache(args.slots, args.max_len)))
     toks = placed(jax.ShapeDtypeStruct((args.slots, 1), jnp.int32))
@@ -109,7 +113,9 @@ def decode_step_memory(args, sharding) -> dict[str, int]:
                                           pos).compile().memory_analysis()
     out = {"arguments": ma.argument_size_in_bytes,
            "outputs": ma.output_size_in_bytes,
-           "temporaries": ma.temp_size_in_bytes}
+           "temporaries": ma.temp_size_in_bytes,
+           "held_params": sum(a.size * a.dtype.itemsize
+                              for a in jax.tree.leaves(held))}
     out["total"] = sum(out.values())
     return out
 

@@ -5,6 +5,7 @@ the encoder-decoder variant (whisper) behind one API:
   loss(params, batch, ctx)         → (scalar, metrics)
   prefill(params, batch, ctx)      → (last_logits, cache)
   decode_step(params, batch, cache, pos, ctx) → (logits, cache)
+  serving_params(params)           → params cast once for serving
 
 batch keys: tokens [B,S] int32; optional prefix_embeds [B,Np,D] (vlm),
 frames [B,Tenc,D] (audio stub).
@@ -19,7 +20,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models import attention, blocks, common
+from repro.models import attention, blocks, common, mamba2
+
+
+def _reads_f32(path) -> bool:
+    """Whether a layer reads the leaf at ``path`` at float32, not
+    through a cast to the compute dtype."""
+    names = [getattr(k, "key", None) for k in path]
+    return "mamba" in names and names[-1] in mamba2.F32_LEAVES
 
 
 def _embed_tokens(params, tokens, cfg: ModelConfig):
@@ -131,6 +139,25 @@ class CausalLM:
         return out[..., :self.cfg.vocab_size]
 
     # ----------------------------------------------------------- serve
+    def serving_params(self, params) -> dict:
+        """``params`` as a server holds them: each floating leaf that the
+        layers read through ``.astype(compute dtype)`` cast to that dtype
+        once, in one jitted call (elementwise, so each leaf keeps its
+        sharding); the leaves a layer reads at float32
+        (``mamba2.F32_LEAVES``) as given.  The decode step's casts are
+        then no-ops: the same values reach the same matmuls, rounded here
+        once instead of in every step."""
+        cdt = self.cfg.cdtype()
+        flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+        leaves = [a for _, a in flat]
+        idx = [i for i, (path, a) in enumerate(flat)
+               if jnp.issubdtype(a.dtype, jnp.floating) and a.dtype != cdt
+               and not _reads_f32(path)]
+        cast = jax.jit(lambda xs: [x.astype(cdt) for x in xs])
+        for i, a in zip(idx, cast([leaves[i] for i in idx])):
+            leaves[i] = a
+        return jax.tree_util.tree_unflatten(treedef, leaves)
+
     def init_cache(self, batch: int, max_len: int):
         return blocks.init_stack_cache(self.cfg, batch, max_len,
                                        self.cfg.cdtype())

@@ -19,6 +19,11 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig, SSMConfig
 from repro.models import common
 
+# Leaves the block reads at float32 (they enter the f32 discretisation and
+# skip path with no cast to the compute dtype); every other leaf is read
+# through ``.astype(x.dtype)``.
+F32_LEAVES = frozenset({"dt_bias", "a_log", "d_skip"})
+
 
 def _dims(cfg: ModelConfig):
     s = cfg.ssm

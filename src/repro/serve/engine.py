@@ -33,13 +33,14 @@ events):
     straggler machinery).
 
 Spans (``repro.obs.span``: profiler annotations while a trace records;
-``uid`` names the request): ``serve.run`` one ``run()``; ``serve.admit``
-one request from the queue into a slot, its prefill included;
-``serve.prefill`` its teacher-forced prompt; ``serve.round`` one decode
-round; per step ``serve.dispatch`` (stall checks, inputs to the device,
-the jitted call), ``serve.sync`` (the argmax and its copy to the host:
-the wait on the device) and ``serve.bookkeep`` (lengths, monitor,
-heartbeat, the ``serve.step`` emission); ``serve.retire``.  The
+``uid`` names the request): ``serve.cast_params`` the weights cast to
+the compute dtype once, at construction; ``serve.run`` one ``run()``;
+``serve.admit`` one request from the queue into a slot, its prefill
+included; ``serve.prefill`` its teacher-forced prompt; ``serve.round``
+one decode round; per step ``serve.dispatch`` (stall checks, inputs to
+the device, the jitted call), ``serve.sync`` (the argmax and its copy to
+the host: the wait on the device) and ``serve.bookkeep`` (lengths,
+monitor, heartbeat, the ``serve.step`` emission); ``serve.retire``.  The
 per-step and per-round spans emit no Event; their times add up in
 ``stats()["host"]`` whether or not ``repro.obs`` is enabled.
 """
@@ -114,12 +115,35 @@ def _decode_fn(model, ctx, shards: int):
     return fn
 
 
+def _cast_counts(given, served) -> dict[str, int]:
+    """What casting ``given`` to ``served`` did: the leaves whose dtype
+    changed and the bytes written for them, and the floating leaves
+    served as given."""
+    cast = kept = nbytes = 0
+    for a, b in zip(jax.tree.leaves(given), jax.tree.leaves(served)):
+        if a.dtype != b.dtype:
+            cast += 1
+            nbytes += b.size * b.dtype.itemsize
+        elif jnp.issubdtype(a.dtype, jnp.floating):
+            kept += 1
+    return {"cast_leaves": cast, "cast_bytes": nbytes, "kept_leaves": kept}
+
+
 class ServingEngine:
     def __init__(self, model, params, cfg: ServeConfig, ctx=None):
         self.model = model
-        self.params = params
         self.cfg = cfg
         self.ctx = ctx
+        # weights in the compute dtype, cast once here (the model's
+        # ``serving_params``) so the decode step converts none; no
+        # reference to the given tree is kept
+        self._self_s: dict[str, float] = {}    # span name -> self seconds
+        served = params
+        if hasattr(model, "serving_params"):
+            with obs.span("serve.cast_params", tally=self._self_s):
+                served = jax.block_until_ready(model.serving_params(params))
+        self._param_counts = _cast_counts(params, served)
+        self.params = served
         self.queue: deque[Request] = deque()
         self.slots: list[Optional[Request]] = [None] * cfg.slots
         self.lengths = np.zeros(cfg.slots, np.int32)
@@ -136,7 +160,6 @@ class ServingEngine:
                       "bookkeep_s": 0.0, "between_s": 0.0, "betweens": 0,
                       "queue_s": 0.0, "admitted": 0,
                       "first_token_wait_s": 0.0, "first_tokens": 0}
-        self._self_s: dict[str, float] = {}    # span name -> self seconds
         self._synced_at: Optional[float] = None   # last step's sync, in run()
         # robustness state: bounded-queue shedding, per-request deadlines,
         # slow-step/straggler detection over per-slot step times
@@ -369,6 +392,11 @@ class ServingEngine:
         ``run()``; ``queue_s`` over ``admitted`` (submit → slot);
         ``first_token_wait_s`` over ``first_tokens`` (slot → first
         generated token); and ``self_s``, each span's self seconds.
+
+        ``params`` counts the cast to the served weights made at
+        construction: ``cast_leaves`` changed dtype, writing
+        ``cast_bytes``; ``kept_leaves`` floating leaves are served as
+        given.
         """
         dec, pre = self._steps["decode"], self._steps["prefill"]
         return {
@@ -391,6 +419,7 @@ class ServingEngine:
             "requests": {uid: dict(rec)
                          for uid, rec in self._requests.items()},
             "host": {**self._host, "self_s": dict(self._self_s)},
+            "params": dict(self._param_counts),
         }
 
     # ------------------------------------------------------------- run
