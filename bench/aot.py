@@ -3,16 +3,21 @@
 time for a described TPU v5e: no chip needed.
 
     JAX_PLATFORMS=cpu python3 bench/aot.py --config yi-9b --slots 8 \
-        --max-len 32768 --shards 4     # over a described 2x2
+        --max-len 2048 --shards 4      # over a described 2x2
     JAX_PLATFORMS=cpu python3 bench/aot.py --config yi-9b --slots 8 \
-        --max-len 32768 --shards 1     # the same deployment on one chip
+        --max-len 2048 --shards 1      # the same deployment on one chip
 
-Prints ``memory_analysis()`` of the step (arguments: weights and KV
-cache; outputs: logits and the new cache, which the engine does not
-donate; temporaries), per device, and the collectives and kernels in the
-compiled program.  The step is the model's ``decode_step`` jitted as the
-engine jits it, with the weights replicated and the cache split along
-the sequence over a mesh when ``shards > 1``.
+Prints ``memory_analysis()`` of the step per device (arguments: the
+engine's weights, cast to the compute dtype, and the KV cache; outputs:
+logits and the new cache, which the engine does not donate;
+temporaries), the tree the benchmark makes (its weights in the
+parameter dtype, replicated, held through the window for the check),
+their total, a bound on a run's peak, and the collectives and kernels
+in the compiled program.  It counts as
+``repro.launch.serve.decode_step_memory`` does, for the model a
+configuration file builds (``bench.program``) and over a mesh: the step
+is the engine's (``repro.serve.engine._decode_fn``), with the weights
+replicated and the cache split along the sequence when ``shards > 1``.
 """
 from __future__ import annotations
 
@@ -37,6 +42,7 @@ def step_memory(config: str, slots: int, max_len: int, n: int,
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from bench import program, spec
+    from repro.serve.engine import _decode_fn
     jax.config.update("jax_enable_compilation_cache", False)
     dims = spec.load_config(config)["dims"]
     model = program.build_model(dims, config)
@@ -56,25 +62,23 @@ def step_memory(config: str, slots: int, max_len: int, n: int,
 
     def kv_spec(path):
         names = [getattr(k, "key", None) for k in path]
-        return (P(None, None, "model") if n > 1 and names[-1] in ("k", "v")
-                else P())
-    params = place(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
-                   lambda _: P())
+        return (P(None, None, "model") if n > 1 and "attn" in names
+                and names[-1] in ("k", "v") else P())
+    held = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    params = place(jax.eval_shape(model.serving_params, held), lambda _: P())
     cache = place(jax.eval_shape(lambda: model.init_cache(slots, max_len)),
                   kv_spec)
     toks = place(jax.ShapeDtypeStruct((slots, 1), jnp.int32), lambda _: P())
     pos = place(jax.ShapeDtypeStruct((slots,), jnp.int32), lambda _: P())
-    if n > 1:
-        step = jax.jit(lambda p, t, c, q: model.decode_step(
-            p, t, c, q, ctx=ctx, shards=n))
-    else:
-        step = jax.jit(lambda p, t, c, q: model.decode_step(p, t, c, q))
-    compiled = step.lower(params, toks, cache, pos).compile()
+    compiled = _decode_fn(model, ctx, n).lower(params, toks, cache,
+                                               pos).compile()
     ma = compiled.memory_analysis()
     text = compiled.as_text()
     out = {"arguments": ma.argument_size_in_bytes,
            "outputs": ma.output_size_in_bytes,
-           "temporaries": ma.temp_size_in_bytes}
+           "temporaries": ma.temp_size_in_bytes,
+           "held_params": sum(a.size * a.dtype.itemsize
+                              for a in jax.tree.leaves(held))}
     out["total"] = sum(out.values())
     out["all-reduce"] = text.count("all-reduce(")
     out["tpu_custom_call"] = text.count('custom_call_target="tpu_custom_call"')
@@ -94,7 +98,8 @@ def main(argv=None) -> int:
           f"{args.shards}: per device "
           f"arguments {m['arguments'] / GIB:.3f} GiB, outputs "
           f"{m['outputs'] / GIB:.3f} GiB, temporaries "
-          f"{m['temporaries'] / GIB:.3f} GiB, total {m['total'] / GIB:.3f} "
+          f"{m['temporaries'] / GIB:.3f} GiB, held tree "
+          f"{m['held_params'] / GIB:.3f} GiB, total {m['total'] / GIB:.3f} "
           f"GiB; {m['all-reduce']} all-reduce, {m['tpu_custom_call']} "
           "tpu_custom_call")
     return 0

@@ -16,6 +16,7 @@ position it reads the gap of the token that precision puts first.
 from __future__ import annotations
 
 import functools
+import json
 
 import jax
 import jax.numpy as jnp
@@ -47,10 +48,10 @@ def _fp8(x):
     return x.astype(jnp.float8_e4m3fn).astype(jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnames=("ref", "dims_items",
+@functools.partial(jax.jit, static_argnames=("ref", "dims_json",
                                               "control"))
-def _gaps(w, toks, nxt, valid, ref, dims_items, control):
-    dims = dict(dims_items)
+def _gaps(w, toks, nxt, valid, ref, dims_json, control):
+    dims = json.loads(dims_json)
     lg = ref.logits(w, toks, dims)
     best = lg.max(-1)
     gap = best - jnp.take_along_axis(lg, nxt[..., None], -1)[..., 0]
@@ -68,7 +69,7 @@ def gaps(ref, w: dict, dims: dict, reqs: list, length: int,
     ``control``, of the float8 reference's first choices) at the same
     positions.  Sequences are padded to ``length``; the attention is
     causal, so padding never reaches a served position."""
-    items = tuple(sorted(dims.items()))
+    dims_json = json.dumps(dims, sort_keys=True)    # hashable, nested too
     got, ctl = [], []
     for i in range(0, len(reqs), BATCH):
         chunk = reqs[i:i + BATCH]
@@ -82,7 +83,7 @@ def gaps(ref, w: dict, dims: dict, reqs: list, length: int,
             nxt[b, p - 1:p - 1 + n] = r.served
             valid[b, p - 1:p - 1 + n] = True
         g, c = _gaps(w, jnp.asarray(toks), jnp.asarray(nxt),
-                     jnp.asarray(valid), ref, items, control)
+                     jnp.asarray(valid), ref, dims_json, control)
         got += [float(x) for x in np.asarray(g)[:len(chunk)]]
         ctl += [float(x) for x in np.asarray(c)[:len(chunk)]]
     return got, (ctl if control else [])

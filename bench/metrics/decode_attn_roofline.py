@@ -1,5 +1,5 @@
 """decode_attn's share of its roofline: the chip's least time for the
-window's decode attention (one call per layer and step, over the
+window's decode attention (one call per attention layer and step, over the
 advanced rows' live context; bench.costs.decode_attn_call) over the
 device time of the kernel's operations in the trace, summed over every
 chip that ran them."""
@@ -14,6 +14,6 @@ def read(rec):
     if not calls:
         return None
     least = sum(
-        costs.least_seconds(*costs.decode_attn_call(rec.dims, kv), rec.peaks)
+        costs.least_seconds(*costs.decode_attn_call(rec.work, kv), rec.peaks)
         for kv in rec.step_kv())
-    return 100.0 * rec.dims["n_layers"] * least / device_s
+    return 100.0 * rec.work["attn_layers"] * least / device_s

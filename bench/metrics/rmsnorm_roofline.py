@@ -1,5 +1,5 @@
 """rmsnorm's share of its roofline: the chip's least time for the
-window's norms (two per layer and a final one per step, over the
+window's norms (each of the step's calls, of its width, over the
 advanced rows; bench.costs.rmsnorm_call) over the device time of the
 kernel's operations in the trace, summed over every chip that ran
 them."""
@@ -13,9 +13,11 @@ def read(rec):
                                                           (0.0, 0))
     if not calls:
         return None
-    per_step = 2 * rec.dims["n_layers"] + 1
-    least = sum(
-        costs.least_seconds(*costs.rmsnorm_call(rec.dims, len(kv)),
-                            rec.peaks)
-        for kv in rec.step_kv())
-    return 100.0 * per_step * least / device_s
+    share = 0.0
+    for width, per_step in rec.work["norms"]:
+        least = sum(
+            costs.least_seconds(*costs.rmsnorm_call(width, len(kv)),
+                                rec.peaks)
+            for kv in rec.step_kv())
+        share += 100.0 * per_step * least / device_s
+    return share

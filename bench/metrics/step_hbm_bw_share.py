@@ -7,6 +7,6 @@ from bench import costs
 def read(rec):
     if rec.peaks is None or not rec.steps:
         return None
-    nbytes = sum(costs.step_bytes(rec.dims, kv) for kv in rec.step_kv())
+    nbytes = sum(costs.step_bytes(rec.work, kv) for kv in rec.step_kv())
     return 100.0 * nbytes / rec.window_s / (
         rec.chips * rec.peaks["hbm_bytes_per_s"])

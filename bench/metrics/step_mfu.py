@@ -8,6 +8,6 @@ from bench import costs
 def read(rec):
     if rec.peaks is None or not rec.steps:
         return None
-    flops = sum(costs.step_flops(rec.dims, kv) for kv in rec.step_kv())
+    flops = sum(costs.step_flops(rec.work, kv) for kv in rec.step_kv())
     return 100.0 * flops / rec.window_s / (rec.chips
                                            * rec.peaks["flops_bf16"])

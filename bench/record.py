@@ -11,7 +11,7 @@ from typing import Optional
 
 @dataclasses.dataclass
 class Record:
-    dims: dict                  # the configuration's sizes
+    work: dict                  # the reference's counts (bench.costs)
     chips: int
     peaks: Optional[dict]       # bench.hw, None off the chip
     window_s: float

@@ -18,13 +18,63 @@ asked.  Nothing here comes from the program under test.
 ``rnd`` rounds every tensor a layer produces and every operand of a
 product; the identity gives the reference, a cast through a narrower
 type gives the control that computes in that type.
+
+The reference also owns its weights' layout (``bench.weights`` draws
+them, ``bench/layouts/dense_decoder.json`` maps them onto the program's
+tree) and the work of a decode step (``work``, read by ``bench.costs``):
+per-layer arrays stacked on a leading ``n_layers`` axis, matrices
+``[in, out]``.
 """
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
 
 HIGHEST = jax.lax.Precision.HIGHEST
+
+# the order fixes each leaf's fold_in index (bench.weights)
+LEAVES = ("embed", "head", "final_norm", "norm1", "wq", "wk", "wv", "wo",
+          "norm2", "w_gate", "w_up", "w_down")
+NORMS = frozenset({"final_norm", "norm1", "norm2"})
+F32_LEAVES = frozenset()        # every leaf is held in the param dtype
+
+
+def shapes(dims: dict) -> dict:
+    d, f, v, n = dims["d_model"], dims["d_ff"], dims["vocab"], \
+        dims["n_layers"]
+    hq, hkv, dh = dims["n_heads"], dims["n_kv_heads"], dims["head_dim"]
+    return {
+        "embed": (v, d), "head": (d, v), "final_norm": (d,),
+        "norm1": (n, d), "wq": (n, d, hq * dh), "wk": (n, d, hkv * dh),
+        "wv": (n, d, hkv * dh), "wo": (n, hq * dh, d), "norm2": (n, d),
+        "w_gate": (n, d, f), "w_up": (n, d, f), "w_down": (n, f, d),
+    }
+
+
+def draw(name: str, key, shape: tuple):
+    """One leaf's float32 values: norm scales U(0.75, 1.25); matrices
+    N(0, 1/fan_in), embeddings N(0, 1/d_model)."""
+    if name in NORMS:
+        return jax.random.uniform(key, shape, jnp.float32, 0.75, 1.25)
+    fan_in = shape[1] if name == "embed" else shape[-2]
+    return jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)
+
+
+def work(dims: dict) -> dict:
+    """What one decode step does per advanced row (``bench.costs``):
+    the weights a token multiplies by (every layer's projections, the
+    three SwiGLU matrices, the head), the norms called ([width, calls]:
+    two a layer and the final one), the layers that hold K/V and call
+    ``decode_attn``, the query width, and the K and V elements of one
+    position in one such layer."""
+    dm, dh, n = dims["d_model"], dims["head_dim"], dims["n_layers"]
+    hq, hkv = dims["n_heads"], dims["n_kv_heads"]
+    layer = dm * (hq + 2 * hkv) * dh + hq * dh * dm + 3 * dm * dims["d_ff"]
+    return {"matmul_params": n * layer + dm * dims["vocab"],
+            "norms": [[dm, 2 * n + 1]], "attn_layers": n,
+            "attn_width": hq * dh, "kv_row": 2 * hkv * dh, "d_model": dm}
 
 
 def _identity(x):

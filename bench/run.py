@@ -119,7 +119,7 @@ def compare(cell, loop, params, seed: int, control: bool):
     is judged by the same limits."""
     from bench import check, program, spec, traffic
     dims, mix = cell.config["dims"], cell.traffic
-    weights = program.from_program(params, dims["vocab"])
+    weights = program.from_program(params, spec.layout(cell.config), dims)
     finished = [r for r in loop.finished()
                 if r.served is not None and len(r.served) == len(r.stamps)]
     picked = check.sample(finished, mix, seed)
@@ -153,7 +153,7 @@ def run_cell(cell, seed: int, seconds: float, traced: bool,
     object.  ``require_chip=False`` lets the CPU tests drive it."""
     jax = setup_jax(cache=require_chip)
     t_jax = time.perf_counter()
-    from bench import hw, program, trace, traffic
+    from bench import hw, program, spec, trace, traffic
     from bench.loop import ClosedLoop
     from bench.record import Record
 
@@ -167,7 +167,7 @@ def run_cell(cell, seed: int, seconds: float, traced: bool,
     if settings["shards"] > 1 and ctx is None:
         raise RunFailed(f"no mesh of {settings['shards']} devices")
     t_model = time.perf_counter()
-    params = program.make_weights(dims, model, seed, ctx)
+    params = program.make_weights(cell.config, model, seed, ctx)
     jax.block_until_ready(params)
     t_weights = time.perf_counter()
     loop = ClosedLoop(program.build_engine(model, params, settings,
@@ -203,7 +203,7 @@ def run_cell(cell, seed: int, seconds: float, traced: bool,
     if xspace is not None:
         tr = trace.reduce(jax.profiler.ProfileData.from_serialized_xspace(
             xspace))
-    rec = Record(dims=dims, chips=cell.chips, peaks=peaks,
+    rec = Record(work=spec.work(cell.config), chips=cell.chips, peaks=peaks,
                  window_s=loop.window_s, setup_s=setup_s, steps=loop.steps,
                  reqs=loop.sent(), stats_open=loop.stats_open,
                  stats_close=loop.stats_close, trace=tr)

@@ -7,7 +7,10 @@ traffic mix, a cell or a metric adds files and entries and edits none:
   bench/traffic/<traffic>.json  the mix's parameters (bench.traffic)
   bench/cells/<workload>.json   engine settings and correctness limits
   bench/metrics/<metric>.py     one reader per metric (``read(rec)``)
-  bench/refs/<reference>.py     the plain reference a config names
+  bench/refs/<reference>.py     the plain reference a config names: its
+                                forward pass, weights and work per step
+  bench/layouts/<reference>.json  the reference's weights on the
+                                program's params tree (bench.program)
 """
 from __future__ import annotations
 
@@ -18,6 +21,9 @@ import pathlib
 
 BENCH_DIR = pathlib.Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
+CONFIG_DIR = BENCH_DIR / "configs"
+REF_DIR = BENCH_DIR / "refs"
+LAYOUT_DIR = BENCH_DIR / "layouts"
 
 
 def _json(path: pathlib.Path) -> dict:
@@ -87,14 +93,36 @@ def load_cell(workload: str) -> Cell:
 
 def load_config(name: str) -> dict:
     """A configuration file, with its sizes also under the bench's own
-    names (``dims``): the file keeps the source's keys, and its
-    ``keys`` table says which of them is which."""
-    cfg = _json(BENCH_DIR / "configs" / f"{name}.json")
-    dims = {ours: cfg["config"][theirs]
-            for ours, theirs in cfg["keys"].items()}
-    dims.update(cfg["derived"])
-    return {**cfg, "dims": dims}
+    names (``dims``): the file keeps the source's keys, its ``keys``
+    table says which of them is which, and ``derived`` holds the rest.
+    A dotted name nests (``moe.n_experts`` → ``dims["moe"]["n_experts"]``)."""
+    cfg = _json(CONFIG_DIR / f"{name}.json")
+    pairs = [(ours, cfg["config"][theirs])
+             for ours, theirs in cfg["keys"].items()]
+    return {**cfg, "dims": nest(pairs + list(cfg["derived"].items()))}
+
+
+def nest(pairs) -> dict:
+    """A nested dict of (dotted name, value) pairs."""
+    out: dict = {}
+    for name, value in pairs:
+        *outer, last = name.split(".")
+        d = out
+        for k in outer:
+            d = d.setdefault(k, {})
+        d[last] = value
+    return out
 
 
 def reference(config: dict):
-    return load_module(BENCH_DIR / "refs" / f"{config['reference']}.py")
+    return load_module(REF_DIR / f"{config['reference']}.py")
+
+
+def layout(config: dict) -> dict:
+    return _json(LAYOUT_DIR / f"{config['reference']}.json")
+
+
+def work(config: dict) -> dict:
+    """What a decode step of the configuration does, by its reference
+    (``bench.costs``)."""
+    return reference(config).work(config["dims"])

@@ -1,4 +1,4 @@
-"""Seeded random weights of a dense decoder, made by the benchmark.
+"""Seeded random weights, made by the benchmark in its reference's layout.
 
 The benchmark owns the weights: it makes them on the device from the
 run's seed, hands them to the program in the program's own layout
@@ -6,32 +6,16 @@ run's seed, hands them to the program in the program's own layout
 program cannot change, to the reference afterwards.  Nothing the
 program computes reaches the reference.
 
-Layout (the reference's): per-layer arrays stacked on a leading
-``n_layers`` axis; matrices are ``[in, out]``.
+The reference (``bench/refs/<reference>.py``) says what there is to
+draw: ``LEAVES``, whose order fixes each leaf's ``fold_in`` index,
+``shapes(dims)``, ``draw(name, key, shape)`` (float32 values) and
+``F32_LEAVES``, the leaves held in float32 whatever the parameter dtype.
 """
 from __future__ import annotations
-
-import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-# the order fixes each leaf's fold_in index
-LEAVES = ("embed", "head", "final_norm", "norm1", "wq", "wk", "wv", "wo",
-          "norm2", "w_gate", "w_up", "w_down")
-
-
-def shapes(dims: dict) -> dict:
-    d, f, v, n = dims["d_model"], dims["d_ff"], dims["vocab"], \
-        dims["n_layers"]
-    hq, hkv, dh = dims["n_heads"], dims["n_kv_heads"], dims["head_dim"]
-    return {
-        "embed": (v, d), "head": (d, v), "final_norm": (d,),
-        "norm1": (n, d), "wq": (n, d, hq * dh), "wk": (n, d, hkv * dh),
-        "wv": (n, d, hkv * dh), "wo": (n, hq * dh, d), "norm2": (n, d),
-        "w_gate": (n, d, f), "w_up": (n, d, f), "w_down": (n, f, d),
-    }
 
 
 def seed_key(seed: int) -> np.ndarray:
@@ -41,20 +25,13 @@ def seed_key(seed: int) -> np.ndarray:
     return ss.generate_state(2).astype(np.uint32)
 
 
-def generate(key, dims: dict) -> dict:
-    """All leaves from one key, in the parameter dtype; jit it."""
+def generate(key, dims: dict, ref) -> dict:
+    """All of ``ref``'s leaves from one key, in the parameter dtype (the
+    float32-held ones in float32); jit it."""
     dt = jnp.dtype(dims["param_dtype"])
     out = {}
-    for name, shape in shapes(dims).items():
-        k = jax.random.fold_in(key, LEAVES.index(name))
-        if name in ("final_norm", "norm1", "norm2"):
-            a = jax.random.uniform(k, shape, jnp.float32, 0.75, 1.25)
-        else:
-            fan_in = shape[1] if name == "embed" else shape[-2]
-            a = jax.random.normal(k, shape, jnp.float32) / math.sqrt(fan_in)
-        out[name] = a.astype(dt)
+    for name, shape in ref.shapes(dims).items():
+        a = ref.draw(name, jax.random.fold_in(key, ref.LEAVES.index(name)),
+                     shape)
+        out[name] = a.astype(jnp.float32 if name in ref.F32_LEAVES else dt)
     return out
-
-
-def n_params(dims: dict) -> int:
-    return sum(math.prod(s) for s in shapes(dims).values())

@@ -16,14 +16,15 @@ GLM_LAYER = 4096 * (32 + 2 * 2) * 128 + 32 * 128 * 4096 + 3 * 4096 * 13696
     ("yi-9b", 8 * 173_015_040 + 4096 * 64_000),
     ("chatglm3-6b", 8 * 203_948_032 + 4096 * 65_024)])
 def test_matmul_params_by_hand(name, matmul):
-    d = spec.load_config(name)["dims"]
+    w = spec.work(spec.load_config(name))
     assert YI_LAYER == 173_015_040 and GLM_LAYER == 203_948_032
-    assert costs.matmul_params(d) == matmul
-    assert costs.norm_params(d) == 17 * 4096
+    assert w["matmul_params"] == matmul
+    assert costs.norm_params(w) == 17 * 4096
+    assert w["attn_layers"] == 8 and w["norms"] == [[4096, 17]]
 
 
 def test_step_flops_yi():
-    d = spec.load_config("yi-9b")["dims"]
+    d = spec.work(spec.load_config("yi-9b"))
     kv = [100] * 8
     # 2 FLOPs per weight per row, plus 4 * 32 heads * 128 * 800 positions
     # of attention in each of the 8 layers
@@ -32,7 +33,7 @@ def test_step_flops_yi():
 
 
 def test_step_bytes_chatglm():
-    d = spec.load_config("chatglm3-6b")["dims"]
+    d = spec.work(spec.load_config("chatglm3-6b"))
     kv = [10, 20]
     weights = (1_897_922_560 + 17 * 4096) * 2
     embed = 2 * 4096 * 2
@@ -43,7 +44,7 @@ def test_step_bytes_chatglm():
 
 
 def test_decode_attn_call_yi():
-    d = spec.load_config("yi-9b")["dims"]
+    d = spec.work(spec.load_config("yi-9b"))
     flops, nbytes = costs.decode_attn_call(d, [2048, 1])
     assert flops == 4 * 32 * 128 * 2049
     # q in and out: 32 x 128 bf16 each per row; K/V: 2 KiB per position
@@ -51,8 +52,8 @@ def test_decode_attn_call_yi():
 
 
 def test_rmsnorm_call_and_least_time():
-    d = spec.load_config("yi-9b")["dims"]
-    flops, nbytes = costs.rmsnorm_call(d, 8)
+    d = spec.work(spec.load_config("yi-9b"))
+    flops, nbytes = costs.rmsnorm_call(d["d_model"], 8)
     assert (flops, nbytes) == (4 * 8 * 4096, 17 * 4096 * 2)
     v5e = hw.peaks("TPU v5 lite")
     assert costs.least_seconds(flops, nbytes, v5e) == nbytes / 819e9

@@ -18,7 +18,7 @@ def _req(sent, stamps):
 
 
 def _rec(reqs=(), steps=(), trace=None, window_s=10.0):
-    return Record(dims=spec.load_config("yi-9b")["dims"], chips=1,
+    return Record(work=spec.work(spec.load_config("yi-9b")), chips=1,
                   peaks=hw.peaks("TPU v5 lite"), window_s=window_s,
                   setup_s=12.5, steps=list(steps),
                   reqs=list(reqs),
@@ -69,7 +69,7 @@ def test_engine_counters():
     rec = _rec(steps=steps)
     assert _reader("prefill_step_share").read(rec) == 25.0
     assert _reader("step_ms").read(rec) == pytest.approx(25.0)
-    d = rec.dims
+    d = rec.work
     flops = costs.step_flops(d, [10, 20]) + costs.step_flops(d, [1])
     assert _reader("step_mfu").read(rec) == pytest.approx(
         100 * flops / 10.0 / 197e12)
@@ -93,11 +93,11 @@ def test_roofline_readers_on_a_trace():
         kernels={"decode_attn": [8e3, 8], "rmsnorm": [17e3, 17]},
         busy_ns={0: 6e9}, window_s=10.0, chips=1)
     rec = _rec(steps=[(0, "decode", [0, 1], [99, 199], 0.02)], trace=tr)
-    d, v5e = rec.dims, rec.peaks
+    d, v5e = rec.work, rec.peaks
     attn = costs.least_seconds(*costs.decode_attn_call(d, [100, 200]), v5e)
     assert _reader("decode_attn_roofline").read(rec) == pytest.approx(
         100 * 8 * attn / 8e-6)
-    norm = costs.least_seconds(*costs.rmsnorm_call(d, 2), v5e)
+    norm = costs.least_seconds(*costs.rmsnorm_call(d["d_model"], 2), v5e)
     assert _reader("rmsnorm_roofline").read(rec) == pytest.approx(
         100 * 17 * norm / 17e-6)
     assert _reader("device_idle_share").read(rec) == pytest.approx(40.0)

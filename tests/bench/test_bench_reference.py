@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import program, weights
+from bench import program, spec, weights
 from bench.refs import dense_decoder
 
 
@@ -18,9 +18,10 @@ from bench.refs import dense_decoder
 def test_reference_matches_program_forward(rope_style):
     dims = dict(bench_fixtures.TINY_DIMS, rope_style=rope_style,
                 compute_dtype="float32")
+    config = {"name": "tiny", "reference": "dense_decoder", "dims": dims}
     model = program.build_model(dims, "tiny")
-    params = program.make_weights(dims, model, 2**31 + 3)
-    w = program.from_program(params, dims["vocab"])
+    params = program.make_weights(config, model, 2**31 + 3)
+    w = program.from_program(params, spec.layout(config), dims)
     toks = jnp.asarray(np.random.default_rng(0).integers(
         0, dims["vocab"], (2, 24)), jnp.int32)
     with jax.default_matmul_precision("highest"):
@@ -35,7 +36,7 @@ def test_rope_styles_differ():
     one style only; make sure the styles give different logits."""
     dims = dict(bench_fixtures.TINY_DIMS)
     key = weights.seed_key(5)
-    w = weights.generate(key, dims)
+    w = weights.generate(key, dims, dense_decoder)
     toks = jnp.arange(12, dtype=jnp.int32)[None]
     full = dense_decoder.logits(w, toks, dims)
     half = dense_decoder.logits(w, toks, dict(dims, rope_style="half"))
@@ -44,9 +45,9 @@ def test_rope_styles_differ():
 
 def test_seeds_make_different_weights_and_one_seed_the_same():
     dims = dict(bench_fixtures.TINY_DIMS)
-    a = weights.generate(weights.seed_key(2**31 + 1), dims)
-    b = weights.generate(weights.seed_key(2**31 + 1), dims)
-    c = weights.generate(weights.seed_key(2**31 + 2), dims)
+    a = weights.generate(weights.seed_key(2**31 + 1), dims, dense_decoder)
+    b = weights.generate(weights.seed_key(2**31 + 1), dims, dense_decoder)
+    c = weights.generate(weights.seed_key(2**31 + 2), dims, dense_decoder)
     assert all(np.array_equal(a[k], b[k]) for k in a)
     assert not np.array_equal(a["wq"], c["wq"])
-    assert set(a) == set(weights.LEAVES)
+    assert set(a) == set(dense_decoder.LEAVES)

@@ -34,7 +34,7 @@ def _host(**kw):
 def _rec(open_host, close_host):
     stats = lambda h: {"prefill_steps": 0, "decode_steps": 0,
                        **({} if h is None else {"host": h})}
-    return Record(dims={}, chips=1, peaks=None, window_s=40.0, setup_s=1.0,
+    return Record(work={}, chips=1, peaks=None, window_s=40.0, setup_s=1.0,
                   steps=[], reqs=[], stats_open=stats(open_host),
                   stats_close=stats(close_host))
 
