@@ -140,7 +140,14 @@ def run(shards, ctx):
     return eng.run()
 ctx = serving_ctx(4)
 assert ctx is not None and ctx.tp == 4
-assert run(4, ctx) == run(1, None)
+compiled = []
+jax.monitoring.register_event_duration_secs_listener(
+    lambda event, secs, fun_name=None, **kw: compiled.append(fun_name)
+    if event == "/jax/core/compile/backend_compile_duration" else None)
+sharded = run(4, ctx)
+# the carry goes back in as it comes out: one executable for every step
+assert compiled.count("jit(engine_step)") == 1, compiled
+assert sharded == run(1, None)
 print("ENGINE_SHARDED_OK")
 """
 

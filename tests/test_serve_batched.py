@@ -30,7 +30,7 @@ def _engine(**kw):
     return ServingEngine(_ToyModel(), None, ServeConfig(**kw))
 
 
-class _CountingDecode:
+class _Counting:
     def __init__(self, fn):
         self.fn = fn
         self.calls = 0
@@ -43,10 +43,10 @@ class _CountingDecode:
 # ------------------------------------------- one fused step per round
 
 def test_one_decode_call_and_event_per_round():
-    """With 2 active slots an engine round is ONE _decode dispatch and
-    ONE serve.step event, not one per slot."""
+    """With 2 active slots an engine round is ONE dispatch of the
+    engine's step and ONE serve.step event, not one per slot."""
     eng = _engine(slots=2, max_new_tokens=3)
-    eng._decode = _CountingDecode(eng._decode)
+    eng._engine_step = _Counting(eng._engine_step)
     with obs.collect() as col:
         eng.submit(1, [1, 2])                 # 1 prefill step
         eng.submit(2, [3])                    # none
@@ -58,7 +58,7 @@ def test_one_decode_call_and_event_per_round():
     assert all(e.attrs["slots"] == [0, 1] for e in decode_events)
     assert all(e.attrs["active_slots"] == 2 for e in decode_events)
     # total dispatches: 1 prefill + 3 fused decode rounds
-    assert eng._decode.calls == 4
+    assert eng._engine_step.calls == 4
     assert eng.stats()["decode_steps"] == 3
 
 
