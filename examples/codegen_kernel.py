@@ -10,7 +10,7 @@ a ``repro.codegen.TraversalSpec``, then walks the whole pipeline:
                  the same op runs in ref (jnp interpreter) and
                  interpret (pallas_call(interpret=True)) modes
   4. registry    one ``register(KernelSpec(...))`` call puts it in the
-                 conformance matrix and the fig6 benchmark list
+                 conformance matrix
 
 Run: PYTHONPATH=src python examples/codegen_kernel.py
 """
@@ -83,9 +83,7 @@ registry.register(registry.KernelSpec(
     traffic=lambda s, dt: traffic_of(saxpy_offset_spec(
         jnp.zeros((s["rows"], s["cols"]), dt),
         jnp.zeros((s["rows"], s["cols"] + OFF), dt)), dt),
-    cache_shape=lambda s: (s["rows"], s["cols"]),
-    bench_sizes={"rows": 8192, "cols": 4096},
-    tags=("paper", "gen")))
+    cache_shape=lambda s: (s["rows"], s["cols"])))
 
 points = [p for p in registry.conformance_points() if p[1] == "saxpy_offset"]
 print(f"\nconformance matrix now carries {len(points)} saxpy_offset rows:")
@@ -97,18 +95,5 @@ for pid, kernel, sizes, cfg in points:
         np.asarray(spec.ref(inputs, cfg)), rtol=1e-4, atol=1e-4)
     print(f"  {pid:24s} ✓ vs oracle")
 
-try:
-    import os
-    import sys
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))      # repo root, for `benchmarks`
-    from benchmarks.fig6_kernels import bench_specs
-    names = [s.name for s in bench_specs()]
-    assert "saxpy_offset" in names
-    print(f"\nfig6 kernel list ({len(names)} kernels) includes "
-          "saxpy_offset — a new fig6 row with zero bespoke plumbing")
-except ImportError:
-    print("\n(run from the repo root to see the fig6 list pick it up)")
-
-print("\nend-to-end: spec → plan → emit → registry → conformance → fig6,"
+print("\nend-to-end: spec → plan → emit → registry → conformance,"
       "\nwithout writing a single pl.pallas_call by hand.")

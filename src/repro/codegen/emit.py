@@ -2,7 +2,7 @@
 
 The scheduled nest's STREAM part becomes D operand refs per traversed
 array — D independent HBM→VMEM DMA pipelines, the TPU rendering of the
-paper's D concurrent strides (same machinery as ``core.pipeline``).  The
+paper's D concurrent strides.  The
 GRID parts become the ``pallas_call`` grid (batch axes lead), UNROLL the
 block rows, VECTOR the lane dimension, and BLOCK tiles (free axes, the
 §5.1.1 cache blocks) ride whole inside every kernel block.  Four

@@ -4,12 +4,9 @@ Public API:
   StridingConfig          — (stride_unroll D, portion_unroll P, lookahead)
   plan / rank_configs     — auto-configuration (paper §6.3 search, modeled)
   plan_transform          — paper §5.1 critical-access selection
-  stream_specs/operands   — Pallas multi-stream grid builders
-  TpuDmaModel / CpuPrefetchModel — latency-hiding analytical models
+  TpuDmaModel             — latency-hiding analytical DMA model
 """
-from repro.core.dma_model import COFFEE_LAKE, TPU_V5E, CpuPrefetchModel, TpuDmaModel
-from repro.core.pipeline import (coalesced_spec, segment_blocks,
-                                 stream_operands, stream_specs)
+from repro.core.dma_model import TPU_V5E, TpuDmaModel
 from repro.core.planner import (Plan, Traffic, plan, rank_configs,
                                 traffic_bytes)
 from repro.core.striding import (SINGLE_STRIDED, StridingConfig, divisors,
@@ -25,6 +22,5 @@ __all__ = [
     "valid_stride_unrolls",
     "Traffic", "Plan", "plan", "rank_configs", "traffic_bytes",
     "ArrayAccess", "LoopNest", "TransformPlan", "plan_transform",
-    "stream_specs", "stream_operands", "coalesced_spec", "segment_blocks",
-    "TpuDmaModel", "CpuPrefetchModel", "TPU_V5E", "COFFEE_LAKE",
+    "TpuDmaModel", "TPU_V5E",
 ]

@@ -1,22 +1,12 @@
-"""Analytical memory-stream concurrency models.
+"""Analytical memory-stream concurrency model.
 
-Two models share one latency-hiding core (Little's law:
-``throughput = min(peak, outstanding_bytes / latency)``):
-
-* ``TpuDmaModel`` — the *target* model: D concurrent HBM→VMEM DMA streams,
-  each a ring of ``lookahead`` block buffers. This is what the planner
-  scores candidate ``StridingConfig``s with, and what the roofline memory
-  term refines.
-
-* ``CpuPrefetchModel`` — the *paper-validation* model: reproduces the shape
-  of the paper's Fig 2/3/4 curves (throughput, stall cycles, hit ratios vs
-  stride count) so `benchmarks/fig2_stream.py` etc. can plot modeled curves
-  next to the CPU wall-clock measurements taken in this container. It is a
-  qualitative model of the Coffee Lake L2 streamer, calibrated to the
-  paper's reported +33%/+13%/+11% read/write/copy gains at 16 strides.
-
-Both models treat the paper's §4.5 collision effect as a multiplicative
-efficiency loss when concurrent streams alias (see ``layout.collides``).
+``TpuDmaModel`` is a latency-hiding (Little's law:
+``throughput = min(peak, outstanding_bytes / latency)``) model of D
+concurrent HBM→VMEM DMA streams, each a ring of ``lookahead`` block
+buffers. This is what the planner scores candidate ``StridingConfig``s
+with, and what the roofline memory term refines. It treats the paper's
+§4.5 collision effect as a multiplicative efficiency loss when concurrent
+streams alias (see ``layout.collides``).
 """
 from __future__ import annotations
 
@@ -26,8 +16,8 @@ import os
 from repro.core import layout
 from repro.core.striding import StridingConfig
 
-__all__ = ["TpuDmaModel", "CpuPrefetchModel", "TPU_V5E", "COFFEE_LAKE",
-           "seeded_descriptor_overhead", "default_tpu_model"]
+__all__ = ["TpuDmaModel", "TPU_V5E", "seeded_descriptor_overhead",
+           "default_tpu_model"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -71,90 +61,15 @@ class TpuDmaModel:
         return min(agg, self.hbm_bw)
 
 
-@dataclasses.dataclass(frozen=True)
-class CpuPrefetchModel:
-    """Qualitative model of a stride-detecting HW prefetcher (paper Fig 2-4).
-
-    Calibration targets (paper §4.3/§4.4/§4.6, Coffee Lake i7-8700):
-      reads  +33% at 16 strides; writes +3-13%; copy +5-11%;
-      prefetcher off: flat-to-declining in D;
-      power-of-two spacing: collapse growing with D (Fig 5).
-    """
-
-    peak_bw: float = 19.87e9       # bytes/s (paper Table 2)
-    mem_latency: float = 81e-9     # s
-    line_bytes: int = 64
-    n_prefetch_engines: int = 16   # streams trackable by L1+L2 prefetchers
-    prefetch_depth_1: float = 13.0 # lines in flight for a single stream
-    depth_decay: float = 0.22      # per-stream depth shrinks as engines split
-    demand_mlp: float = 10.0       # demand-miss parallelism (MLBP w/o prefetch)
-
-    def lines_in_flight(self, d: int, prefetch_on: bool = True) -> float:
-        if not prefetch_on:
-            # out-of-order window sustains ~demand_mlp misses regardless of D,
-            # slightly degrading with D (more DTLB/issue pressure).
-            return self.demand_mlp * (1.0 - 0.004 * (d - 1))
-        engaged = min(d, self.n_prefetch_engines)
-        depth = self.prefetch_depth_1 / (1.0 + self.depth_decay * (engaged - 1)) ** 0.5
-        extra = self.demand_mlp * 0.35
-        total = engaged * depth + extra
-        if d > self.n_prefetch_engines:  # un-tracked streams demand-miss
-            total *= self.n_prefetch_engines / d
-        return total
-
-    def throughput(self, d: int, prefetch_on: bool = True,
-                   aliased: bool = False, write_fraction: float = 0.0) -> float:
-        lines = self.lines_in_flight(d, prefetch_on)
-        if aliased and d > 1:
-            # concurrent streams hitting one set evict each other's
-            # prefetched lines; grows with D (Fig 5).
-            lines /= (1.0 + 0.45 * (d - 1))
-        bw = min(lines * self.line_bytes / self.mem_latency, self.peak_bw)
-        if write_fraction > 0:
-            # RFO + writeback halves effective useful bandwidth share and the
-            # prefetcher covers only the read part (paper: writes gain less).
-            read_bw = bw
-            wb_cost = 1.0 + write_fraction
-            bw = read_bw / wb_cost
-        return bw
-
-    # -- Fig 3/4 derived observables ------------------------------------
-    def hit_ratio(self, d: int, level: str, prefetch_on: bool = True) -> float:
-        """Modeled cache hit ratio at L1/L2/L3 (paper Fig 4)."""
-        if not prefetch_on:
-            return {"l1": 0.5, "l2": 0.0, "l3": 0.0}[level]
-        cover = self.lines_in_flight(d, True) / (
-            self.lines_in_flight(self.n_prefetch_engines, True))
-        cover = min(cover, 1.0)
-        if level == "l1":
-            return 0.5  # consumption outruns L1 fill (paper §4.3)
-        if level == "l2":
-            return min(0.25 + 0.55 * cover, 0.9)
-        if level == "l3":
-            return min(0.45 + 0.5 * cover, 0.95)
-        raise ValueError(level)
-
-    def stall_cycles_per_line(self, d: int, freq_hz: float = 3.2e9,
-                              prefetch_on: bool = True) -> float:
-        """Modeled execution stalls w/ outstanding loads per line (Fig 3)."""
-        bw = self.throughput(d, prefetch_on)
-        t_line = self.line_bytes / bw
-        t_min = self.line_bytes / self.peak_bw
-        return max(t_line - 0.25 * t_min, 0.0) * freq_hz
-
-
 TPU_V5E = TpuDmaModel()
-COFFEE_LAKE = CpuPrefetchModel()
 
 
 def seeded_descriptor_overhead(default: float = 0.3e-6) -> float:
     """Per-descriptor issue cost, seedable from a measurement.
 
-    ``REPRO_DMA_DESCRIPTOR_NS`` (nanoseconds, as fitted by
-    ``benchmarks/descriptor_sweep.py`` — on real v5e, by the same sweep
-    against HBM DMA) overrides the static default, so the ranked
-    ``block_rows`` ordering is testable and calibratable without
-    hardware access."""
+    ``REPRO_DMA_DESCRIPTOR_NS`` (nanoseconds) overrides the static
+    default, so the ranked ``block_rows`` ordering is testable and can
+    take a value fitted on the chip; no such fit exists yet."""
     env = os.environ.get("REPRO_DMA_DESCRIPTOR_NS")
     return float(env) * 1e-9 if env else default
 

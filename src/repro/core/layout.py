@@ -61,8 +61,8 @@ def collides(spacing_bytes: int, alias_bits: int = ALIAS_BITS) -> bool:
 
     The exact-power-of-two criterion matches both the paper's design
     (2.0 GiB collapses, 1.9 GiB doesn't — 1.9 GiB spacing has a large odd
-    factor) and our host measurement (benchmarks/fig5: 256 MiB arrays
-    degrade 19-43% vs 192 MiB = 3·2^26). Modern LLCs hash the set index,
+    factor) and an earlier host measurement (256 MiB arrays degraded
+    19-43% vs 192 MiB = 3·2^26). Modern LLCs hash the set index,
     so only exact 2^k strides alias through the hash; a single odd factor
     (the paper's row padding, our lane padding) de-aliases."""
     if spacing_bytes < (1 << alias_bits):

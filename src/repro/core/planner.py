@@ -107,8 +107,7 @@ def rank_configs(traffic: Traffic,
 
     ``model=None`` scores with :func:`~repro.core.dma_model.
     default_tpu_model`, whose descriptor term is seedable via
-    ``REPRO_DMA_DESCRIPTOR_NS`` (measured by
-    ``benchmarks/descriptor_sweep.py``).
+    ``REPRO_DMA_DESCRIPTOR_NS``.
     """
     if model is None:
         model = default_tpu_model()

@@ -41,5 +41,4 @@ register(KernelSpec(
     default_sizes=_SIZES, aliased_sizes=_ALIASED,
     traffic=_wire_traffic,
     cache_shape=lambda s: (s["rows"], s["cols"]),
-    bench_sizes={"rows": 4096, "cols": 1024},
-    rtol=1e-5, atol=1e-6, tags=("framework",)))
+    rtol=1e-5, atol=1e-6))

@@ -39,8 +39,8 @@ def _adamw(p, g, m, v, lr, b1, b2, eps, wd, bc1, bc2,
         # (the tiles ARE the traversal) but its reshape boundaries make
         # XLA recompute the shared (m', v') staging inside each of the
         # three output fusions — 14 array-wide multiplies instead of 9,
-        # the BENCH_PR4 1.133 gen_vs_hand outlier.  The spec's axes only
-        # describe the traversal; evaluate() never tiles, so a 2-D
+        # once measured as a 1.133 gen_vs_hand outlier.  The spec's axes
+        # only describe the traversal; evaluate() never tiles, so a 2-D
         # stand-in spec plus native-rank operands is exact.
         spec = specs.adamw_spec(p.reshape(-1, shape[-1]) if p.ndim > 1
                                 else p.reshape(1, -1), None, None, None)

@@ -21,5 +21,4 @@ register(KernelSpec(
     default_sizes=_SIZES, aliased_sizes=_ALIASED,
     traffic=lambda s, dt: Traffic(rows=s["m"], cols=s["n"], dtype=dt,
                                   read_arrays=2),
-    cache_shape=lambda s: (s["m"], s["n"]),
-    bench_sizes={"m": 4096, "n": 4096}, tags=("paper",)))
+    cache_shape=lambda s: (s["m"], s["n"])))

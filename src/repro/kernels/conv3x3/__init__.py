@@ -21,5 +21,4 @@ register(KernelSpec(
     default_sizes=_SIZES, aliased_sizes=_ALIASED,
     traffic=lambda s, dt: _Traffic(rows=s["h"] - 2, cols=s["w"], dtype=dt,
                                    read_arrays=3, write_arrays=1),
-    cache_shape=lambda s: (s["h"], s["w"]),
-    bench_sizes={"h": 2050, "w": 2048}, tags=("paper",)))
+    cache_shape=lambda s: (s["h"], s["w"])))

@@ -22,5 +22,4 @@ register(KernelSpec(
     traffic=lambda s, dt: Traffic(rows=s["r"] * s["q"], cols=s["s"],
                                   dtype=dt, read_arrays=1, write_arrays=1,
                                   resident_bytes=s["s"] * s["s"] * 4),
-    cache_shape=lambda s: (s["r"], s["q"], s["s"]),
-    bench_sizes={"r": 16, "q": 256, "s": 256}, tags=("paper",)))
+    cache_shape=lambda s: (s["r"], s["q"], s["s"])))

@@ -12,7 +12,6 @@ __all__ = ["gemver", "gemver_outer", "gemver_sum", "gemver_mxv1",
 
 _SIZES = {"m": 48, "n": 256}
 _ALIASED = {"m": 32, "n": 128}   # 4 KiB inter-stream spacing (§4.5)
-_BENCH = {"m": 4096, "n": 4096}
 
 
 def _shape(s):
@@ -33,7 +32,7 @@ register(KernelSpec(
     default_sizes=_SIZES, aliased_sizes=_ALIASED,
     traffic=lambda s, dt: Traffic(rows=s["m"], cols=s["n"], dtype=dt,
                                   read_arrays=1, write_arrays=1),
-    cache_shape=_shape, bench_sizes=_BENCH, tags=("paper",)))
+    cache_shape=_shape))
 
 register(KernelSpec(
     name="gemver_sum", family="gemver", fn=gemver_sum,
@@ -46,8 +45,7 @@ register(KernelSpec(
     # the 1-D loop is blocked into [vn/1024, 1024] tiles (§5.1.1)
     traffic=lambda s, dt: Traffic(rows=max(s["vn"] // 1024, 4), cols=1024,
                                   dtype=dt, read_arrays=2, write_arrays=1),
-    cache_shape=lambda s: (s["vn"],),
-    bench_sizes={"vn": 4 * 2**20}, tags=("paper",)))
+    cache_shape=lambda s: (s["vn"],)))
 
 register(KernelSpec(
     name="gemver_mxv1", family="gemver", fn=gemver_mxv1,
@@ -60,7 +58,7 @@ register(KernelSpec(
     default_sizes=_SIZES, aliased_sizes=_ALIASED,
     traffic=lambda s, dt: Traffic(rows=s["m"], cols=s["n"], dtype=dt,
                                   read_arrays=2),
-    cache_shape=_shape, bench_sizes=_BENCH, tags=("paper",)))
+    cache_shape=_shape))
 
 register(KernelSpec(
     name="gemver_mxv2", family="gemver", fn=gemver_mxv2,
@@ -72,7 +70,7 @@ register(KernelSpec(
     default_sizes=_SIZES, aliased_sizes=_ALIASED,
     traffic=lambda s, dt: Traffic(rows=s["m"], cols=s["n"], dtype=dt,
                                   read_arrays=1),
-    cache_shape=_shape, bench_sizes=_BENCH, tags=("paper",)))
+    cache_shape=_shape))
 
 register(KernelSpec(
     name="gemver", family="gemver", fn=gemver,
@@ -86,5 +84,5 @@ register(KernelSpec(
     default_sizes=_SIZES, aliased_sizes=_ALIASED,
     traffic=lambda s, dt: Traffic(rows=s["m"], cols=s["n"], dtype=dt,
                                   read_arrays=1, write_arrays=1),
-    cache_shape=_shape, bench_sizes=_BENCH,
-    rtol=1e-3, atol=1e-3, tags=("paper",)))
+    cache_shape=_shape,
+    rtol=1e-3, atol=1e-3))

@@ -137,13 +137,10 @@ def _traffic(build, shapes_fn):
 # exercises identical (sizes × (D,P)) points for hand and generated
 _STREAM_SIZES = {"rows": 32, "cols": 256}
 _STREAM_ALIASED = {"rows": 32, "cols": 128}
-_STREAM_BENCH = {"rows": 8192, "cols": 4096}
 _MXV_SIZES = {"m": 48, "n": 256}
 _MXV_ALIASED = {"m": 32, "n": 128}
-_MXV_BENCH = {"m": 4096, "n": 4096}
 _JAC_SIZES = {"h": 34, "w": 130}
 _JAC_ALIASED = {"h": 34, "w": 128}
-_JAC_BENCH = {"h": 2050, "w": 2048}
 
 
 def _rc(s):
@@ -163,7 +160,7 @@ register(KernelSpec(
     default_sizes=_STREAM_SIZES, aliased_sizes=_STREAM_ALIASED,
     traffic=_traffic(copy_spec, lambda s: (_rc(s),)),
     traversal=_ir(copy_spec, lambda s: (_rc(s),)),
-    cache_shape=_rc, bench_sizes=_STREAM_BENCH, tags=("paper", "gen")))
+    cache_shape=_rc))
 
 register(KernelSpec(
     name="stream_triad_gen", family="gen", fn=stream_triad_gen,
@@ -175,7 +172,7 @@ register(KernelSpec(
     default_sizes=_STREAM_SIZES, aliased_sizes=_STREAM_ALIASED,
     traffic=_traffic(triad_spec, lambda s: (_rc(s), _rc(s))),
     traversal=_ir(triad_spec, lambda s: (_rc(s), _rc(s))),
-    cache_shape=_rc, bench_sizes=_STREAM_BENCH, tags=("paper", "gen")))
+    cache_shape=_rc))
 
 register(KernelSpec(
     name="mxv_gen", family="gen", fn=mxv_gen,
@@ -188,8 +185,7 @@ register(KernelSpec(
     traffic=_traffic(mxv_spec,
                      lambda s: ((s["m"], s["n"]), (s["n"],))),
     traversal=_ir(mxv_spec, lambda s: ((s["m"], s["n"]), (s["n"],))),
-    cache_shape=_mn,
-    bench_sizes=_MXV_BENCH, tags=("paper", "gen")))
+    cache_shape=_mn))
 
 register(KernelSpec(
     name="jacobi2d_gen", family="gen", fn=jacobi2d_gen,
@@ -200,8 +196,7 @@ register(KernelSpec(
     traffic=_traffic(jacobi_spec, lambda s: ((s["h"], s["w"]),)),
     traversal=_ir(jacobi_spec, lambda s: ((s["h"], s["w"]),)),
     cache_shape=lambda s: (s["h"], s["w"]),
-    bench_sizes=_JAC_BENCH,
-    rtol=1e-5, atol=1e-5, tags=("paper", "gen")))
+    rtol=1e-5, atol=1e-5))
 
 # lane-multiple extents: the padded-lanes refusal under a non-'sum'
 # per-write combinator never triggers at these sizes
@@ -215,8 +210,7 @@ register(KernelSpec(
     traffic=_traffic(rowstat_spec, lambda s: (_mn(s),)),
     traversal=_ir(rowstat_spec, lambda s: (_mn(s),)),
     cache_shape=_mn,
-    bench_sizes=_MXV_BENCH,
-    rtol=1e-5, atol=1e-5, tags=("paper", "gen")))
+    rtol=1e-5, atol=1e-5))
 
 register(KernelSpec(
     name="transpose_gen", family="gen", fn=transpose_gen,
@@ -227,8 +221,7 @@ register(KernelSpec(
     default_sizes=_MXV_SIZES, aliased_sizes=_MXV_ALIASED,
     traffic=_traffic(transpose_spec, lambda s: (_mn(s),)),
     traversal=_ir(transpose_spec, lambda s: (_mn(s),)),
-    cache_shape=_mn,
-    bench_sizes=_MXV_BENCH, tags=("paper", "gen")))
+    cache_shape=_mn))
 
 
 # the remaining ported families register on import (they self-register

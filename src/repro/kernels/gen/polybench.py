@@ -197,7 +197,6 @@ _S = jax.ShapeDtypeStruct   # traversal rows build IR on placeholders
 
 _MN_SIZES = {"m": 48, "n": 256}
 _MN_ALIASED = {"m": 32, "n": 128}
-_MN_BENCH = {"m": 4096, "n": 4096}
 
 
 def _mn(s):
@@ -219,7 +218,7 @@ register(KernelSpec(
     traversal=lambda s, dt: (
         bicg_q_spec(_S(_mn(s), dt), _S((s["n"],), dt)),
         bicg_s_spec(_S(_mn(s), dt), _S((s["m"],), dt))),
-    cache_shape=_mn, bench_sizes=_MN_BENCH, tags=("paper", "gen")))
+    cache_shape=_mn))
 
 register(KernelSpec(
     name="gemver_outer_gen", family="gen", fn=gemver_outer_gen,
@@ -235,7 +234,7 @@ register(KernelSpec(
         gemver_outer_spec(jnp.zeros(_mn(s), dt), *(None,) * 4), dt),
     traversal=lambda s, dt: gemver_outer_spec(_S(_mn(s), dt),
                                               *(None,) * 4),
-    cache_shape=_mn, bench_sizes=_MN_BENCH, tags=("paper", "gen")))
+    cache_shape=_mn))
 
 register(KernelSpec(
     name="gemver_sum_gen", family="gen", fn=gemver_sum_gen,
@@ -248,8 +247,7 @@ register(KernelSpec(
     traffic=lambda s, dt: traffic_of(
         gemver_sum_spec(jnp.zeros((s["vn"],), dt), None), dt),
     traversal=lambda s, dt: gemver_sum_spec(_S((s["vn"],), dt), None),
-    cache_shape=lambda s: (s["vn"],),
-    bench_sizes={"vn": 4 * 2**20}, tags=("paper", "gen")))
+    cache_shape=lambda s: (s["vn"],)))
 
 register(KernelSpec(
     name="gemver_mxv1_gen", family="gen", fn=gemver_mxv1_gen,
@@ -264,7 +262,7 @@ register(KernelSpec(
     traffic=lambda s, dt: Traffic(rows=s["m"], cols=s["n"], dtype=dt,
                                   read_arrays=2),
     traversal=lambda s, dt: gemver_mxv1_spec(_S(_mn(s), dt), None),
-    cache_shape=_mn, bench_sizes=_MN_BENCH, tags=("paper", "gen")))
+    cache_shape=_mn))
 
 register(KernelSpec(
     name="gemver_mxv1_sum_gen", family="gen", fn=gemver_mxv1_sum_gen,
@@ -279,7 +277,7 @@ register(KernelSpec(
     traffic=lambda s, dt: Traffic(rows=s["m"], cols=s["n"], dtype=dt,
                                   read_arrays=2),
     traversal=lambda s, dt: gemver_mxv1_sum_spec(_S(_mn(s), dt), None),
-    cache_shape=_mn, bench_sizes=_MN_BENCH, tags=("paper", "gen")))
+    cache_shape=_mn))
 
 register(KernelSpec(
     name="gemver_mxv2_gen", family="gen", fn=gemver_mxv2_gen,
@@ -293,7 +291,7 @@ register(KernelSpec(
                                   read_arrays=1),
     traversal=lambda s, dt: gemver_mxv2_spec(_S(_mn(s), dt),
                                              _S((s["n"],), dt)),
-    cache_shape=_mn, bench_sizes=_MN_BENCH, tags=("paper", "gen")))
+    cache_shape=_mn))
 
 register(KernelSpec(
     name="conv3x3_gen", family="gen", fn=conv3x3_gen,
@@ -306,8 +304,7 @@ register(KernelSpec(
     traffic=lambda s, dt: traffic_of(
         conv3x3_spec(jnp.zeros((s["h"], s["w"]), dt)), dt),
     traversal=lambda s, dt: conv3x3_spec(_S((s["h"], s["w"]), dt)),
-    cache_shape=lambda s: (s["h"], s["w"]),
-    bench_sizes={"h": 2050, "w": 2048}, tags=("paper", "gen")))
+    cache_shape=lambda s: (s["h"], s["w"])))
 
 register(KernelSpec(
     name="doitgen_gen", family="gen", fn=doitgen_gen,
@@ -323,5 +320,4 @@ register(KernelSpec(
                      jnp.zeros((s["s"], s["s"]), dt)), dt),
     traversal=lambda s, dt: doitgen_spec(_S((s["r"], s["q"], s["s"]), dt),
                                          _S((s["s"], s["s"]), dt)),
-    cache_shape=lambda s: (s["r"], s["q"], s["s"]),
-    bench_sizes={"r": 16, "q": 256, "s": 256}, tags=("paper", "gen")))
+    cache_shape=lambda s: (s["r"], s["q"], s["s"])))

@@ -19,5 +19,4 @@ register(KernelSpec(
     traffic=lambda s, dt: _Traffic(rows=s["h"] - 2, cols=s["w"], dtype=dt,
                                    read_arrays=3, write_arrays=1),
     cache_shape=lambda s: (s["h"], s["w"]),
-    bench_sizes={"h": 2050, "w": 2048},
-    rtol=1e-5, atol=1e-5, tags=("paper",)))
+    rtol=1e-5, atol=1e-5))

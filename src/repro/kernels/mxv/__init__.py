@@ -9,7 +9,6 @@ __all__ = ["mxv", "mxv_t"]
 
 _SIZES = {"m": 48, "n": 256}
 _ALIASED = {"m": 32, "n": 128}   # (32/4)*128*4 B = 4 KiB spacing (§4.5)
-_BENCH = {"m": 4096, "n": 4096}
 
 
 def _shape(s):
@@ -25,7 +24,7 @@ register(KernelSpec(
     default_sizes=_SIZES, aliased_sizes=_ALIASED,
     traffic=lambda s, dt: Traffic(rows=s["m"], cols=s["n"], dtype=dt,
                                   read_arrays=1),
-    cache_shape=_shape, bench_sizes=_BENCH, tags=("paper",)))
+    cache_shape=_shape))
 
 register(KernelSpec(
     name="mxv_t", family="mxv", fn=mxv_t,
@@ -36,4 +35,4 @@ register(KernelSpec(
     default_sizes=_SIZES, aliased_sizes=_ALIASED,
     traffic=lambda s, dt: Traffic(rows=s["m"], cols=s["n"], dtype=dt,
                                   read_arrays=2),
-    cache_shape=_shape, bench_sizes=_BENCH, tags=("paper",)))
+    cache_shape=_shape))

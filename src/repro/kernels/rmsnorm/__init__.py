@@ -22,5 +22,4 @@ register(KernelSpec(
                                    read_arrays=1, write_arrays=1,
                                    resident_bytes=s["dm"] * 4),
     cache_shape=lambda s: (s["t"], s["dm"]),
-    bench_sizes={"t": 4096, "dm": 4096},
-    rtol=1e-5, atol=1e-5, tags=("framework",)))
+    rtol=1e-5, atol=1e-5))

@@ -12,7 +12,6 @@ _SIZES = {"rows": 32, "cols": 256}
 # (32/4) rows * 128 cols * 4 B = 4 KiB inter-stream spacing → exact
 # power of two at the aliasing granularity (paper §4.5)
 _ALIASED = {"rows": 32, "cols": 128}
-_BENCH = {"rows": 8192, "cols": 4096}
 
 
 def _traffic(reads, writes):
@@ -32,8 +31,7 @@ register(KernelSpec(
     run=lambda inp, cfg, mode: stream_read(inp[0], config=cfg, mode=mode),
     ref=lambda inp, cfg: _ref.read_ref(inp[0], cfg.stride_unroll),
     default_sizes=_SIZES, aliased_sizes=_ALIASED,
-    traffic=_traffic(1, 0), cache_shape=_shape,
-    bench_sizes=_BENCH, tags=("paper",)))
+    traffic=_traffic(1, 0), cache_shape=_shape))
 
 register(KernelSpec(
     name="stream_copy", family="stream", fn=stream_copy,
@@ -41,8 +39,7 @@ register(KernelSpec(
     run=lambda inp, cfg, mode: stream_copy(inp[0], config=cfg, mode=mode),
     ref=lambda inp, cfg: _ref.copy_ref(inp[0]),
     default_sizes=_SIZES, aliased_sizes=_ALIASED,
-    traffic=_traffic(1, 1), cache_shape=_shape,
-    bench_sizes=_BENCH, tags=("paper",)))
+    traffic=_traffic(1, 1), cache_shape=_shape))
 
 register(KernelSpec(
     name="stream_init", family="stream", fn=stream_init,
@@ -51,8 +48,7 @@ register(KernelSpec(
                                            config=cfg, mode=mode),
     ref=lambda inp, cfg: _ref.init_ref(inp[0], inp[1], inp[2]),
     default_sizes=_SIZES, aliased_sizes=_ALIASED,
-    traffic=_traffic(0, 1), cache_shape=_shape,
-    bench_sizes=_BENCH, tags=("paper",)))
+    traffic=_traffic(0, 1), cache_shape=_shape))
 
 register(KernelSpec(
     name="stream_copy_manual", family="stream", fn=stream_copy_manual,
@@ -61,5 +57,4 @@ register(KernelSpec(
                                                   mode=mode),
     ref=lambda inp, cfg: _ref.copy_ref(inp[0]),
     default_sizes=_SIZES, aliased_sizes=_ALIASED,
-    traffic=_traffic(1, 1), cache_shape=_shape,
-    bench_sizes=_BENCH, tags=("paper",)))
+    traffic=_traffic(1, 1), cache_shape=_shape))

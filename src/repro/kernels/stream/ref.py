@@ -1,4 +1,4 @@
-"""Pure-jnp oracles for the stream micro-kernels (paper §4 benchmarks)."""
+"""Pure-jnp oracles for the stream micro-kernels (paper §4)."""
 from __future__ import annotations
 
 import jax.numpy as jnp

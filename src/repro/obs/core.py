@@ -22,7 +22,7 @@ ship: :class:`MemoryCollector` (tests, programmatic inspection) and the
 JSONL file sink in :mod:`repro.obs.sinks`.
 
 This module imports nothing from the rest of ``repro`` so any layer
-(core, registry, kernels, serve, benchmarks) can instrument without an
+(core, registry, kernels, serve) can instrument without an
 import cycle.
 """
 from __future__ import annotations

@@ -50,26 +50,19 @@ class KernelSpec:
     traffic: Optional[Callable[[Mapping[str, int], Any], Any]] = None
     cache_shape: Optional[Callable[[Mapping[str, int]], tuple]] = None
     traversal: Optional[Callable[[Mapping[str, int], Any], Any]] = None
-    bench_sizes: Optional[Mapping[str, int]] = None  # benchmark-scale problem
     rtol: float = 1e-4
     atol: float = 1e-4
-    tags: tuple[str, ...] = ()     # ("paper",) / ("framework",)
 
     def __post_init__(self):
         if not self.name.isidentifier():
             raise ValueError(f"spec name {self.name!r} is not exportable")
-
-    @property
-    def bench_problem(self) -> dict:
-        return dict(self.bench_sizes if self.bench_sizes is not None
-                    else self.default_sizes)
 
 
 _REGISTRY: dict[str, KernelSpec] = {}
 
 # The kernel families the registry discovers on first use.  A new family
 # only needs its package listed here and a register() call in its
-# __init__ — tests, benchmarks and exports then pick it up automatically.
+# __init__ — tests and exports then pick it up automatically.
 FAMILIES = ("stream", "mxv", "bicg", "gemver", "conv3x3", "jacobi2d",
             "doitgen", "decode_attn", "rmsnorm", "adamw", "gen")
 

@@ -76,9 +76,8 @@ def test_rank_configs_scores_staggered_spacing_for_aliased_layouts():
 
 
 def test_descriptor_overhead_seeded_from_env(monkeypatch):
-    """REPRO_DMA_DESCRIPTOR_NS (benchmarks/descriptor_sweep.py's fitted
-    value) seeds the model's per-transfer descriptor term; unseeded, the
-    default model is exactly TPU_V5E."""
+    """REPRO_DMA_DESCRIPTOR_NS seeds the model's per-transfer descriptor
+    term; unseeded, the default model is exactly TPU_V5E."""
     from repro.core.dma_model import TPU_V5E, default_tpu_model
     monkeypatch.delenv("REPRO_DMA_DESCRIPTOR_NS", raising=False)
     assert default_tpu_model() == TPU_V5E

@@ -171,16 +171,3 @@ def test_retired_names_still_resolve_through_registry():
         spec = registry.get(name)
         assert spec.name == name
         assert callable(spec.run) and callable(spec.ref)
-
-
-def test_fig6_is_generated_only():
-    """fig6's paired rows compare generated kernels against the XLA
-    oracle — no hand kernel name survives as a timing target."""
-    from benchmarks.fig6_kernels import RETIRED_HAND_KERNELS, gen_specs
-    assert set(RETIRED) | set(RETIRED_PR6) <= set(RETIRED_HAND_KERNELS)
-    names = {s.name for s in gen_specs()}
-    assert names and all(n.endswith("_gen") for n in names)
-    assert not (names & set(RETIRED_HAND_KERNELS))
-    # the former gen-vs-hand pairings now ride the oracle pairing
-    assert {"jacobi2d_gen", "decode_attn_gen", "adamw_update_gen",
-            "bicg_gen", "conv3x3_gen", "rmsnorm_gen"} <= names

@@ -24,17 +24,17 @@ Three modes:
 
       1. ``pallas_call`` is constructed only under ``src/repro/codegen/``
          — any ``.pallas_call`` attribute or ``from ... import
-         pallas_call`` elsewhere in src/benchmarks/tests/tools fails
+         pallas_call`` elsewhere in src/tests/tools fails
          (subsumes the old CI grep, and docstrings no longer false-
          positive);
       2. every kernel family package ships a ``specs.py`` and every
          ``kernels/gen`` module lowers builders imported from one —
          plus every gen-family registry row publishes a ``traversal``
          so the sweep above actually covers it;
-      3. every obs event/counter/span name emitted from src/ or
-         benchmarks/ appears in the README § Observability table.
+      3. every obs event/counter/span name emitted from src/ appears in
+         the README § Observability table.
 
-Exit codes (the ``bench_compare.py`` convention): 0 = clean; 1 =
+Exit codes: 0 = clean; 1 =
 findings/violations; 2 = missing/malformed input or a fixture whose
 expected rule did not fire.
 """
@@ -53,7 +53,7 @@ __all__ = ["SpeclintError", "sweep", "run_fixture", "repo_lint",
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # dirs the pallas containment rule scans / the one dir allowed to emit
-_SCAN_DIRS = ("src", "benchmarks", "tests", "tools")
+_SCAN_DIRS = ("src", "tests", "tools")
 _EMITTER_PREFIX = os.path.join("src", "repro", "codegen") + os.sep
 
 
@@ -252,7 +252,7 @@ def _lint_specs_layout(root: str) -> list[str]:
 def collect_emitted_names(root: str) -> dict[str, str]:
     """{event name: file:line} for every literal obs emission."""
     names: dict[str, str] = {}
-    for path in _py_files(root, ("src", "benchmarks")):
+    for path in _py_files(root, ("src",)):
         rel = os.path.relpath(path, root)
         for node in ast.walk(_parse(path)):
             if not (isinstance(node, ast.Call)
